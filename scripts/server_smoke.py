@@ -17,52 +17,46 @@ asserts, end to end:
 Run:  PYTHONPATH=src python scripts/server_smoke.py
 (The Makefile `server-smoke` target and the CI server-smoke job wrap
 this in a timeout.)
+
+One process per device: this parent never imports JAX. The server child
+holds the device while it serves; the virtual-clock reference runs in a
+second child (`--reference`, fed the captured streams as JSON on stdin)
+started only after the server child has exited.
 """
 import asyncio
+import json
 import os
 import pathlib
 import signal
 import subprocess
 import sys
 import threading
-import time
 
 import numpy as np
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
+SRC = pathlib.Path(__file__).resolve().parents[1] / "src"
+sys.path.insert(0, str(SRC))
 
-from repro.core import QoESpec                                  # noqa: E402
-from repro.core.request import ReqState, Request                # noqa: E402
 from repro.obs.metrics import parse_prometheus                  # noqa: E402
-from repro.serving import (Tolerance, ToleranceSpec,            # noqa: E402
-                           compare_requests)
-from repro.server import (ServerConfig, astream, build_engine,  # noqa: E402
-                          fetch, stream)
+from repro.server.client import astream, fetch, stream          # noqa: E402
 
 N_CONCURRENT = 8
 OUT_LEN = 12
 PROMPT_LEN = 9
-SPEC = QoESpec(ttft=1.0, tds=4.8)
-# same CI-generous gates as tests/test_tolerance.py's in-process
-# differential: wide enough for shared-runner sleep jitter, tight enough
-# to catch a host that cannot keep the smoke-model schedule at all
-GATES = ToleranceSpec(
-    ttft_mean_diff=Tolerance(abs_tol=0.5),
-    ttft_p95_diff=Tolerance(abs_tol=1.0),
-    ttft_max_diff=Tolerance(abs_tol=2.0),
-    tds_mean_diff=Tolerance(abs_tol=2.0, rel_tol=0.5),
-    qoe_mean_diff=Tolerance(abs_tol=0.30),
-    qoe_max_diff=Tolerance(abs_tol=0.60),
-    qoe_mean_of=Tolerance(abs_tol=0.30),
-)
+TTFT, TDS = 1.0, 4.8
+
+
+def child_env():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
+    return env
 
 
 def start_server():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.server", "--port", "0"],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=env)
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        env=child_env())
     lines = []
     port_box = {}
     ready = threading.Event()
@@ -88,19 +82,9 @@ def prompts_for(rids):
         0, 1 << 14, PROMPT_LEN).tolist() for rid in rids}
 
 
-def as_request(rid, prompt_len, evs):
-    acc = next(d for k, d in evs if k == "accepted")
-    toks = [d for k, d in evs if k == "token"]
-    r = Request(rid=rid, arrival=float(acc["arrival"]),
-                prompt_len=prompt_len, output_len=OUT_LEN, spec=SPEC)
-    r.emit_times = [float(d["t"]) for d in toks]
-    r.output_tokens = [int(d["token"]) for d in toks]
-    r.generated = len(toks)
-    r.state = ReqState.FINISHED
-    return r
-
-
 def differential_round(port):
+    """Stream N concurrent requests; return what the wire carried, for
+    the virtual-clock reference child to compare against."""
     rids = list(range(N_CONCURRENT))
     prompts = prompts_for(rids)
 
@@ -112,26 +96,72 @@ def differential_round(port):
             for rid in rids])
 
     results = asyncio.run(fan_out())
-    cand = []
+    streams = []
     for rid, evs in zip(rids, results):
         kinds = [k for k, _ in evs]
         assert kinds[0] == "accepted", kinds
         assert kinds[-1] == "finish", kinds
         assert kinds.count("token") == OUT_LEN, kinds
-        cand.append(as_request(rid, PROMPT_LEN, evs))
-    print(f"streamed {len(cand)} concurrent SSE responses")
+        toks = [d for k, d in evs if k == "token"]
+        streams.append({
+            "rid": rid, "prompt": prompts[rid],
+            "arrival": float(evs[0][1]["arrival"]),
+            "emit_times": [float(d["t"]) for d in toks],
+            "tokens": [int(d["token"]) for d in toks],
+        })
+    print(f"streamed {len(streams)} concurrent SSE responses")
+    return streams
 
-    # virtual-clock reference: identical engine build, identical prompts,
-    # the server's actual arrival stamps
-    cfg, ref_eng = build_engine(ServerConfig(clock="virtual"))
-    ref = [Request(rid=r.rid, arrival=r.arrival, prompt_len=PROMPT_LEN,
-                   output_len=OUT_LEN, spec=SPEC,
-                   prompt_tokens=np.asarray(prompts[r.rid], np.int32))
-           for r in cand]
+
+def reference_main():
+    """`--reference`: virtual-clock reference run of the captured streams
+    (JSON on stdin): identical engine build, identical prompts, the
+    server's actual arrival stamps. Token ids must match exactly; the
+    wall-clock TTFT/TDS/QoE must agree within the tolerance gates."""
+    from repro.core import QoESpec
+    from repro.core.request import ReqState, Request
+    from repro.server import ServerConfig, build_engine
+    from repro.serving import Tolerance, ToleranceSpec, compare_requests
+
+    spec = QoESpec(ttft=TTFT, tds=TDS)
+    # same CI-generous gates as tests/test_tolerance.py's in-process
+    # differential: wide enough for shared-runner sleep jitter, tight
+    # enough to catch a host that cannot keep the smoke-model schedule
+    gates = ToleranceSpec(
+        ttft_mean_diff=Tolerance(abs_tol=0.5),
+        ttft_p95_diff=Tolerance(abs_tol=1.0),
+        ttft_max_diff=Tolerance(abs_tol=2.0),
+        tds_mean_diff=Tolerance(abs_tol=2.0, rel_tol=0.5),
+        qoe_mean_diff=Tolerance(abs_tol=0.30),
+        qoe_max_diff=Tolerance(abs_tol=0.60),
+        qoe_mean_of=Tolerance(abs_tol=0.30),
+    )
+    streams = json.load(sys.stdin)
+    cand = []
+    for st in streams:
+        r = Request(rid=st["rid"], arrival=st["arrival"],
+                    prompt_len=PROMPT_LEN, output_len=OUT_LEN, spec=spec)
+        r.emit_times = st["emit_times"]
+        r.output_tokens = st["tokens"]
+        r.generated = len(st["tokens"])
+        r.state = ReqState.FINISHED
+        cand.append(r)
+    _, ref_eng = build_engine(ServerConfig(clock="virtual"))
+    ref = [Request(rid=st["rid"], arrival=st["arrival"],
+                   prompt_len=PROMPT_LEN, output_len=OUT_LEN, spec=spec,
+                   prompt_tokens=np.asarray(st["prompt"], np.int32))
+           for st in streams]
     ref_eng.run(ref, max_iterations=4000)
-    rep = compare_requests(ref, cand, GATES)
+    rep = compare_requests(ref, cand, gates)
     print(rep.summary())
     rep.assert_ok()
+
+
+def reference_round(streams):
+    out = subprocess.run(
+        [sys.executable, __file__, "--reference"],
+        input=json.dumps(streams), text=True, env=child_env(), timeout=600)
+    assert out.returncode == 0, f"reference run exited {out.returncode}"
 
 
 def metrics_round(port):
@@ -180,7 +210,7 @@ def main():
     try:
         st, _ = fetch("127.0.0.1", port, "/healthz")
         assert st == 200
-        differential_round(port)
+        streams = differential_round(port)
         metrics_round(port)
         drain_round(proc, port)
         code = proc.wait(timeout=60)
@@ -189,9 +219,14 @@ def main():
     finally:
         if proc.poll() is None:
             proc.kill()
+    reference_round(streams)
+    assert "jax" not in sys.modules, "the parent must leave the device alone"
     print("OK: server smoke passed (SSE framing, token identity, "
           "tolerance gates, /metrics, graceful drain)")
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:] == ["--reference"]:
+        reference_main()
+    else:
+        main()

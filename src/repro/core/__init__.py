@@ -7,6 +7,7 @@ from repro.core.latency_model import (
     HardwareSpec,
     LatencyModel,
     SpeculativeLatencyModel,
+    hardware_for_device,
 )
 from repro.core.objectives import (
     FLEET_OBJECTIVES,
@@ -64,6 +65,7 @@ __all__ = [
     "QoESpec", "FluidQoE", "pace_delivery", "qoe_exact", "predict_request_qoe",
     "FLEET_OBJECTIVES", "fleet_avg_qoe", "fleet_min_qoe", "fleet_slo_attainment",
     "HardwareSpec", "LatencyModel", "SpeculativeLatencyModel",
+    "hardware_for_device",
     "TPU_V5E", "TPU_V5E_POD", "A100_4X", "A40_4X",
     "Scheduler", "SchedulerConfig", "SchedulingPolicy",
     "FCFSScheduler", "RoundRobinScheduler",

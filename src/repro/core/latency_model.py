@@ -47,6 +47,28 @@ A100_1X = dataclasses.replace(A100_4X, chips=1, efficiency=0.50,
 A40_4X = HardwareSpec("4xA40", 150e12, 696e9, 64e9, chips=4,
                       efficiency=0.40, overhead=0.015)
 
+# Chip profiles keyed by the `device_kind` JAX reports for an attached
+# accelerator (peaks: Google Cloud documentation, "TPU v5e").
+HARDWARE_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hardware_for_device(device) -> HardwareSpec:
+    """The HardwareSpec a serving engine paces against on `device` (a
+    `jax.Device`). The CPU has no profile of its own: it runs the virtual
+    clock's TPU_V5E. An accelerator whose kind is not in the table is an
+    error, never a default."""
+    if device.platform == "cpu":
+        return TPU_V5E
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for {device.platform} device_kind "
+            f"{device.device_kind!r}; known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}") from None
+
 
 class LatencyModel:
     """Analytic latency for decode / prefill / swap on a given deployment."""

@@ -23,7 +23,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -129,17 +128,17 @@ def moe_apply_ep(p, x: jax.Array, cfg: ModelConfig, mesh,
     if valid is None:
         def wrapper(xl, router, gate, up, down):
             return local_fn(xl, None, router, gate, up, down)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             wrapper, mesh=mesh,
             in_specs=(args_in[0],) + args_in[2:],
             out_specs=(P(dp_spec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], experts["gate"], experts["up"], experts["down"])
     else:
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             local_fn, mesh=mesh, in_specs=args_in,
             out_specs=(P(dp_spec, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, valid, p["router"], experts["gate"], experts["up"],
           experts["down"])
 

@@ -5,7 +5,8 @@ Model code calls these entry points with an ``impl`` string:
 - ``"ref"``     — pure-jnp oracle (XLA-lowered). Used on CPU, in the multi-pod
                   dry-run (cost_analysis sees native HLO), and as ground truth.
 - ``"pallas"``  — the Pallas TPU kernel. On a CPU backend it runs in
-                  interpret mode automatically (correctness path for tests).
+                  interpret mode automatically (correctness path for tests);
+                  any backend other than CPU or TPU is refused.
 - ``"chunked"`` — (scans only) chunked associative-scan in pure XLA: the
                   compile-friendly parallel form used for training/prefill at
                   scale; validated against the sequential oracle.
@@ -27,7 +28,14 @@ DEFAULT_IMPL = "ref"
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode on the CPU (the tests' correctness path);
+    compiled kernels on a TPU; any other backend has no kernels here."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernels for backend {backend!r}")
 
 
 # ---------------------------------------------------------------------------

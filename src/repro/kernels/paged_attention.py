@@ -37,7 +37,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.decode_attention import _decode_kernel
-from repro.kernels.pallas_compat import CompilerParams
 
 
 def _paged_decode_kernel(
@@ -127,7 +126,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, group, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # pages of one request chain through the online softmax, so the
             # page axis is sequential; batch and kv heads stay parallel
             dimension_semantics=("parallel", "parallel", "arbitrary"),

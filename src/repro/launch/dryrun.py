@@ -188,8 +188,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax<0.5 wraps the dict in a list
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     colls = collective_stats(hlo_text)
     fstats = flop_stats(hlo_text)

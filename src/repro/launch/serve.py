@@ -15,12 +15,13 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.core import (
     A100_4X,
-    TPU_V5E,
     LatencyModel,
     QoESpec,
     SchedulerConfig,
+    hardware_for_device,
     make_scheduler,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import Request, ServingEngine
 from repro.serving.simulator import ServingSimulator, SimConfig
 from repro.workload import make_workload
@@ -47,13 +48,18 @@ def run_sim(args) -> None:
 
 def run_engine(args) -> None:
     import jax
+    import jax.numpy as jnp
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     from repro.models import Model
 
-    model = Model(cfg)
+    # full configs run bf16 weights and KV cache: f32 does not fit a chip
+    if args.smoke:
+        cfg, dtype = get_smoke_config(args.arch), jnp.float32
+    else:
+        cfg, dtype = get_config(args.arch), jnp.bfloat16
+    model = Model(cfg, param_dtype=dtype)
     params = model.init(jax.random.PRNGKey(args.seed))
-    lat = LatencyModel(cfg, TPU_V5E)
+    lat = LatencyModel(cfg, hardware_for_device(jax.devices()[0]))
     rng = np.random.default_rng(args.seed)
     wl = []
     for i in range(args.requests):
@@ -68,7 +74,7 @@ def run_engine(args) -> None:
                            SchedulerConfig(objective=args.objective))
     eng = ServingEngine(model, params, sched, lat, num_slots=args.slots,
                         max_seq=args.max_seq,
-                        capacity_tokens=args.kv_capacity)
+                        capacity_tokens=args.kv_capacity, cache_dtype=dtype)
     out = eng.run(wl)
     done = [r for r in out if r.generated >= r.output_len]
     print(f"engine: {len(done)}/{len(wl)} finished, "
@@ -97,6 +103,7 @@ def main() -> None:
         if args.mode == "engine" and not args.smoke:
             print("note: full configs on CPU are slow; use --smoke")
         args.kv_capacity = min(args.kv_capacity, args.slots * args.max_seq)
+        enable_compile_cache()
         run_engine(args)
 
 

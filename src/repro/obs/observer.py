@@ -144,7 +144,7 @@ class Observer:
 
     def drain(self, t, phase, conns, live, *, replica=-1):
         """Graceful-shutdown progress: phase in {"begin","waiting",
-        "done","timeout"} with `conns` open connections and `live`
+        "done","timeout","failed"} with `conns` open connections and `live`
         unfinished requests remaining."""
 
 

@@ -5,7 +5,8 @@
                    (Prometheus text), GET /healthz; per-connection
                    backpressure, disconnect-cancel, graceful drain.
   ServerConfig   — knobs (host/port, arch, clock mode, queue depth).
-  build_engine   — the smoke ServingEngine a standalone server runs.
+  build_engine   — the ServingEngine a standalone server runs (an arch's
+                   smoke preset, or its published widths in bf16).
   format_sse / SSEParser — wire framing + incremental decoder.
   stream / fetch — minimal blocking client helpers (tests, examples).
 

@@ -50,6 +50,7 @@ import math
 import queue
 import threading
 import time
+import traceback
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -65,13 +66,23 @@ from repro.server.sse import format_sse
 _MAX_HEADER_BYTES = 65536
 _MAX_BODY_BYTES = 1 << 20
 
+# Engine size for a full-width (`ServerConfig.full`) server on one 16 GB
+# TPU v5e: slots x max_seq of bf16 KV next to the bf16 weights, with the
+# largest bucketed prefill (FULL_SLOTS rows x FULL_MAX_SEQ tokens) still
+# in memory. tests/test_tpu_compile.py compiles both programs for the chip
+# at this size and checks the bytes.
+FULL_SLOTS = 8
+FULL_MAX_SEQ = 1024
+
 
 @dataclasses.dataclass
 class ServerConfig:
     """Knobs for a ServingServer (CLI flags in `python -m repro.server`)."""
     host: str = "127.0.0.1"
     port: int = 0                  # 0 = OS-assigned; read server.port after start
-    arch: str = "llama3-8b"        # smoke-config architecture
+    arch: str = "llama3-8b"
+    full: bool = False             # published widths in bf16 (False = the
+                                   # arch's smoke preset in f32)
     clock: str = "wall"            # "wall" = real-time pacing (the point)
     scheduler: str = "andes"
     num_slots: int = 4
@@ -84,26 +95,34 @@ class ServerConfig:
 
 
 def build_engine(config: ServerConfig):
-    """Construct the smoke-model ServingEngine a standalone server runs.
+    """Construct the ServingEngine a standalone server runs: the arch's
+    smoke preset in f32 or, with `config.full`, its published widths
+    with bf16 weights and KV cache (f32 does not fit one 16 GB chip at
+    full width). The wall clock paces against the HardwareSpec of the
+    device JAX sees (`hardware_for_device`).
 
     Split out so tests and the bench can build the identical engine with
     `clock="virtual"` for the differential reference."""
     import jax
+    import jax.numpy as jnp
 
-    from repro.configs import get_smoke_config
-    from repro.core import TPU_V5E, LatencyModel, make_scheduler
+    from repro.configs import get_config, get_smoke_config
+    from repro.core import LatencyModel, hardware_for_device, make_scheduler
     from repro.models import Model
     from repro.serving import ServingEngine
 
-    cfg = get_smoke_config(config.arch)
-    model = Model(cfg)
+    if config.full:
+        cfg, dtype = get_config(config.arch), jnp.bfloat16
+    else:
+        cfg, dtype = get_smoke_config(config.arch), jnp.float32
+    model = Model(cfg, param_dtype=dtype)
     params = model.init(jax.random.PRNGKey(0))
-    lat = LatencyModel(cfg, TPU_V5E)
+    lat = LatencyModel(cfg, hardware_for_device(jax.devices()[0]))
     sched = make_scheduler(config.scheduler, config.num_slots * config.max_seq,
                            lat)
     eng = ServingEngine(model, params, sched, lat,
                         num_slots=config.num_slots, max_seq=config.max_seq,
-                        clock=config.clock)
+                        clock=config.clock, cache_dtype=dtype)
     return cfg, eng
 
 
@@ -152,6 +171,7 @@ class ServingServer:
         self._conns: Dict[int, _Conn] = {}     # pump-owned registry
         self._next_conn = 0
         self._draining = False
+        self.failure: Optional[BaseException] = None   # set if step() raised
         self._started = False
         self._stopped = threading.Event()
         self._loop: Optional[asyncio.AbstractEventLoop] = None
@@ -205,9 +225,10 @@ class ServingServer:
                  timeout: Optional[float] = None) -> str:
         """Stop serving. With `drain`, refuse new streams (503) and wait
         up to `timeout` (default config.drain_timeout) for live ones to
-        finish. Returns the terminal drain phase: "done" or "timeout"."""
+        finish. Returns the terminal drain phase: "done", "timeout", or
+        "failed" when the engine pump died (see `failure`)."""
         if not self._started or self._stopped.is_set():
-            return "done"
+            return "failed" if self.failure is not None else "done"
         timeout = self.config.drain_timeout if timeout is None else timeout
         self._draining = True
         t = self._now()
@@ -228,6 +249,8 @@ class ServingServer:
             self._loop.call_soon_threadsafe(self._loop.stop)
         if self._loop_thread is not None:
             self._loop_thread.join(timeout=10)
+        if self.failure is not None:
+            phase = "failed"
         self._observer_call("drain", self._now(), phase, len(self._conns),
                             self._live_count())
         self._stopped.set()
@@ -342,9 +365,11 @@ class ServingServer:
     async def _stream(self, reader: asyncio.StreamReader,
                       writer: asyncio.StreamWriter, body: bytes) -> None:
         t = self._now()
-        if self._draining:
+        if self._draining or self.failure is not None:
             self._observer_call("connection", t, -1, "reject")
-            await self._respond(writer, 503, {"error": "draining"})
+            await self._respond(writer, 503, {
+                "error": "draining" if self.failure is None else
+                f"engine failed: {self.failure!r}"})
             return
         try:
             payload = json.loads(body.decode("utf-8")) if body else {}
@@ -440,6 +465,30 @@ class ServingServer:
 
     # ------------------------------------------------------------ pump side
     def _pump(self) -> None:
+        """Engine thread body. A failure in the backend ends every live
+        stream with an `error` frame and is kept in `failure`, so
+        `shutdown()` reports it instead of the server going on listening
+        with streams that never end."""
+        try:
+            self._pump_loop()
+        except Exception as e:
+            traceback.print_exc()
+            self.failure = e
+            frame = {"event": "error", "message": f"engine failed: {e!r}"}
+            conns = list(self._conns.values())
+            self._conns.clear()
+            # streams whose submit was still queued end the same way
+            while True:
+                try:
+                    cmd = self._cmds.get_nowait()
+                except queue.Empty:
+                    break
+                if cmd[0] == "submit":
+                    conns.append(cmd[2])
+            for conn in conns:
+                self._post(conn, [dict(frame)], final=True)
+
+    def _pump_loop(self) -> None:
         while True:
             try:
                 while True:

@@ -47,8 +47,6 @@ for arch in ["llama3-8b", "qwen2-moe-a2.7b", "falcon-mamba-7b", "zamba2-2.7b"]:
     f = jax.jit(model.loss, in_shardings=(shard, bs))
     compiled = f.lower(params, b).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax<0.5 wraps the dict in a list
-        cost = cost[0] if cost else {}
     results[arch] = {"ok": ok, "flops": float(cost.get("flops", 0))}
 
     # decode path compiles too
@@ -65,7 +63,7 @@ print(json.dumps(results))
 @pytest.mark.slow
 def test_sharding_rules_compile_on_small_mesh():
     env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", SMALL_MESH_SCRIPT],
         capture_output=True, text=True, env=env, timeout=560,

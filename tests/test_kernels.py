@@ -274,3 +274,14 @@ def test_paged_decode_ops_dispatch():
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(via_pallas), np.asarray(expect),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    """Kernels interpret on the CPU, compile on a TPU, and any other
+    backend is refused rather than silently interpreted."""
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda b=backend: b)
+        assert ops._interpret() is want
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._interpret()

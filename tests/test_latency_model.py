@@ -1,8 +1,11 @@
 """Roofline-derived latency model (Appendix B/D adaptation)."""
+import types
+
 import pytest
 
 from repro.configs import get_config
-from repro.core import A100_4X, LatencyModel, TPU_V5E_POD
+from repro.core import (A100_4X, TPU_V5E, TPU_V5E_POD, LatencyModel,
+                        hardware_for_device)
 
 
 CFG = get_config("opt-66b")
@@ -62,3 +65,15 @@ def test_ssm_state_weight():
     l_small = lat.iter_latency(32, 32 * 100)
     l_big = lat.iter_latency(32, 32 * 100_000)
     assert l_big == pytest.approx(l_small, rel=1e-6)
+
+
+def test_hardware_lookup_by_device_kind():
+    """Wall-clock pacing uses the profile of the chip JAX reports; the
+    CPU runs the virtual clock's TPU_V5E; an unknown chip is an error."""
+    dev = types.SimpleNamespace
+    assert hardware_for_device(dev(platform="cpu", device_kind="cpu")) \
+        is TPU_V5E
+    assert hardware_for_device(
+        dev(platform="tpu", device_kind="TPU v5 lite")) is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v9"):
+        hardware_for_device(dev(platform="tpu", device_kind="TPU v9"))

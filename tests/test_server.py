@@ -191,6 +191,42 @@ def test_backpressure_evicts_slow_consumer(sim_server):
     assert conn.queue.empty()
 
 
+class _FailingSim(ServingSimulator):
+    """A backend whose device step fails as soon as it has work."""
+
+    def step(self, *args, **kwargs):
+        if self.has_work:
+            raise RuntimeError("device lost")
+        return super().step(*args, **kwargs)
+
+
+def test_backend_failure_ends_streams_with_error():
+    """A failure in the pump's step() must not leave streams hanging:
+    live ones end with an `error` frame, new ones are refused, and
+    shutdown() reports the failure (so `python -m repro.server` exits
+    non-zero)."""
+    cfg = get_smoke_config("llama3-8b")
+    lat = LatencyModel(cfg, TPU_V5E)
+    backend = _FailingSim(make_scheduler("andes", 4_000, lat), lat,
+                          SimConfig(kv_capacity_tokens=4_000))
+    srv = ServingServer(ServerConfig(clock="virtual", warmup=False),
+                        backend=backend)
+    srv.start()
+    try:
+        evs = collect("127.0.0.1", srv.port,
+                      {"prompt_len": 6, "max_tokens": 5}, timeout=30)
+        assert evs[-1][0] == "error", evs
+        assert "device lost" in evs[-1][1]["message"]
+        assert isinstance(srv.failure, RuntimeError)
+        again = collect("127.0.0.1", srv.port,
+                        {"prompt_len": 6, "max_tokens": 5}, timeout=30)
+        assert again[0][0] == "http_error" and again[0][1]["status"] == 503
+    finally:
+        phase = srv.shutdown(drain=True, timeout=5)
+    assert phase == "failed"
+    assert srv.registry.value("drain_events_total", phase="failed") == 1
+
+
 # ---------------------------------------------------------------------------
 # engine-backed virtual server: token identity over the wire
 # ---------------------------------------------------------------------------
