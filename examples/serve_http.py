@@ -36,6 +36,7 @@ Run:  PYTHONPATH=src python examples/serve_http.py
 import json
 import pathlib
 
+from repro.obs import TraceRecorder
 from repro.server import ServerConfig, ServingServer, collect, fetch
 
 OUT = pathlib.Path(__file__).resolve().parents[1] / "out"
@@ -46,6 +47,8 @@ def main():
     print("=== 1. boot a wall-clock server (smoke engine, real-time "
           "pacing) ===")
     srv = ServingServer(ServerConfig(clock="wall", warmup=True))
+    trace = TraceRecorder()          # the server-side event trace
+    srv.backend.attach_observer(trace)
     port = srv.start()
     print(f"listening on 127.0.0.1:{port} "
           f"(clock={srv.backend.clock}, warmup done)\n")
@@ -81,7 +84,7 @@ def main():
     (OUT / "serve_http_stream.json").write_text(
         json.dumps([{"event": k, **d} for k, d in events], indent=2) + "\n")
     (OUT / "serve_http_metrics.prom").write_text(prom)
-    (OUT / "serve_http_trace.jsonl").write_text(srv.trace.to_jsonl())
+    (OUT / "serve_http_trace.jsonl").write_text(trace.to_jsonl())
     print(f"\nartifacts: {OUT / 'serve_http_stream.json'}, "
           f"{OUT / 'serve_http_metrics.prom'}, "
           f"{OUT / 'serve_http_trace.jsonl'}")

@@ -32,6 +32,21 @@ Consumers:
                                     formalized into the same registry the
                                     benchmarks read
 
+Two records, on two clocks:
+
+  Observer hooks    (obs.observer)  instants on the engine's clock (the
+                                    virtual clock, or the paced wall
+                                    clock): what happened to whom, when
+  program spans     (obs.spans)     durations on the profiler's clock:
+                                    `span(name, **args)` is
+                                    `jax.profiler.TraceAnnotation`, so
+                                    while a trace runs each span lands in
+                                    the same trace as the device's
+                                    operations, and a device-idle gap can
+                                    be put down to the host span it fell
+                                    in (bench/spans.py reads them);
+                                    `SPAN_NAMES` lists them
+
 The default observer is None everywhere — instrumentation points guard
 with a single `is not None` test, so an unobserved run executes the exact
 pre-observability code path. The verification spine is differential

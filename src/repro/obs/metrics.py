@@ -571,7 +571,9 @@ def register_backend_gauges(registry: MetricsRegistry, backend,
 
     KV occupancy (current / peak tokens, utilization, slots in use) comes
     straight off `backend.kv` (PR 5's peak tracking, now readable from
-    outside); clock and live-set size work for any SteppableBackend.
+    outside); clock and live-set size work for any SteppableBackend; an
+    engine's `hotpath_stats()` adds the seconds it slept to its
+    LatencyModel and its prefill's real and padded prompt tokens.
     Bound gauges survive `backend.reset()` because `KVSlotManager.reset()`
     clears the same object in place."""
     labels = {} if replica is None else {"replica": str(replica)}
@@ -584,6 +586,17 @@ def register_backend_gauges(registry: MetricsRegistry, backend,
          lambda: backend.now)
     bind("backend_live_requests", "live (admitted, unfinished) requests",
          lambda: len(backend.live))
+    if hasattr(backend, "hotpath_stats"):
+        # the engine's own hot-path counters (ServingEngine.hotpath_stats)
+        for name, help in (
+                ("pace_seconds_total",
+                 "wall seconds slept to the LatencyModel's schedule"),
+                ("prefill_tokens_real_total",
+                 "prompt tokens through the bucketed prefill"),
+                ("prefill_tokens_padded_total",
+                 "rows x length bucket of the bucketed prefill's calls")):
+            bind(name, help,
+                 lambda name=name: backend.hotpath_stats()[name])
     kv = getattr(backend, "kv", None)
     if kv is not None:
         bind("kv_tokens_used", "KV cache tokens currently resident",

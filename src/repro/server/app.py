@@ -18,7 +18,13 @@ real time. The moving parts:
       POST /v1/stream   JSON body -> SSE stream of lifecycle frames
                         (accepted / token / preempt / finish / shed /
                         cancel), mapping StreamHandle events 1:1.
-      GET  /metrics     Prometheus text from the live MetricsRegistry.
+      GET  /metrics     Prometheus text from the live MetricsRegistry,
+                        including the engine's hot-path series bound by
+                        `register_backend_gauges`: pace_seconds_total
+                        (wall seconds slept to the LatencyModel),
+                        prefill_tokens_real_total and
+                        prefill_tokens_padded_total (prompt tokens, and
+                        rows x length bucket, of the bucketed prefill).
       GET  /healthz     liveness + clock mode + live-connection count.
 
 * **Backpressure.** Each connection owns a bounded `asyncio.Queue`; a
@@ -34,6 +40,11 @@ real time. The moving parts:
   flush volume go through `Observer.connection` / `Observer.sse_flush`,
   so the trace/metrics layers see the wire exactly like they see the
   scheduler.
+
+* **Spans.** Both threads open named profiler spans (`repro.obs.spans`):
+  `server.submit` and `server.write` on the loop, `pump.wait`,
+  `pump.apply` and `pump.flush` on the pump, so a profiler trace puts
+  each wait of a request down to the thread and layer it sat in.
 
 Wall-clock timestamps in SSE frames are engine-relative seconds (the
 same clock as the trace events), so a captured stream can be compared
@@ -59,8 +70,9 @@ from repro.api import ServingClient, SubmitOptions
 from repro.core import QoESpec, make_network
 from repro.core.request import Request
 from repro.core.token_buffer import TokenBuffer
-from repro.obs import MetricsObserver, MetricsRegistry, TraceRecorder, compose
+from repro.obs import MetricsObserver, MetricsRegistry
 from repro.obs.metrics import register_backend_gauges
+from repro.obs.spans import span
 from repro.server.sse import format_sse
 
 _MAX_HEADER_BYTES = 65536
@@ -149,8 +161,10 @@ class ServingServer:
 
     Pass a prebuilt `backend` (anything ServingClient accepts) to serve
     it directly, or leave it None to build the smoke engine described by
-    `config`. The server owns a TraceRecorder + MetricsRegistry attached
-    alongside any observers the backend already has.
+    `config`. The server owns a MetricsRegistry, fed by a MetricsObserver
+    attached alongside any observers the backend already has; a caller
+    that wants the event trace attaches its own `TraceRecorder` to the
+    backend before `start()`.
     """
 
     def __init__(self, config: Optional[ServerConfig] = None, *,
@@ -161,9 +175,7 @@ class ServingServer:
         self.model_cfg = model_cfg
         self.backend = backend
         self.registry = MetricsRegistry()
-        self.trace = TraceRecorder()
-        backend.attach_observer(
-            compose(self.trace, MetricsObserver(self.registry)))
+        backend.attach_observer(MetricsObserver(self.registry))
         register_backend_gauges(self.registry, backend)
         self.client = ServingClient(backend)
         self.port: Optional[int] = None
@@ -387,7 +399,10 @@ class ServingServer:
                      b"Cache-Control: no-cache\r\n"
                      b"Connection: close\r\n\r\n")
         await writer.drain()
-        self._cmds.put(("submit", payload, conn))
+        rid = payload.get("rid")
+        with span("server.submit", conn=conn.conn_id,
+                  rid=rid if isinstance(rid, int) else -1):
+            self._cmds.put(("submit", payload, conn))
 
         # EOF on the read side = client went away; an SSE client never
         # sends more bytes after the request, so any read completion
@@ -410,10 +425,15 @@ class ServingServer:
                 batch = get_task.result()
                 if batch is None:              # sentinel: stream complete
                     break
+                first = next((ev["index"] for ev in batch
+                              if ev["event"] == "token"), -1)
                 frame = b"".join(format_sse(ev.pop("event"), ev)
                                  for ev in batch)
-                writer.write(frame)
-                await writer.drain()
+                with span("server.write", conn=conn.conn_id,
+                          rid=conn.handle.rid if conn.handle else -1,
+                          first_index=first, frames=len(batch)):
+                    writer.write(frame)
+                    await writer.drain()
                 self._observer_call("sse_flush", self._now(), conn.conn_id,
                                     conn.handle.rid if conn.handle else -1,
                                     len(batch), len(frame))
@@ -500,7 +520,8 @@ class ServingServer:
             self._flush_all()
             if not progressed:
                 try:
-                    cmd = self._cmds.get(timeout=0.02)
+                    with span("pump.wait"):
+                        cmd = self._cmds.get(timeout=0.02)
                 except queue.Empty:
                     continue
                 if self._apply(cmd):
@@ -512,26 +533,30 @@ class ServingServer:
     def _apply(self, cmd) -> bool:
         """Execute one command on the pump thread. True = stop."""
         kind = cmd[0]
-        if kind == "stop":
-            # anything still connected gets a terminal frame so its
-            # handler coroutine wakes up and closes
-            for conn in list(self._conns.values()):
-                self._post(conn, [{"event": "shutdown", "t": self._now()}],
-                           final=True)
-                self._conns.pop(conn.conn_id, None)
-            return True
-        if kind == "cancel":
-            self.client.cancel(cmd[1])
+        with span("pump.apply", kind=kind) as sp:
+            if kind == "stop":
+                # anything still connected gets a terminal frame so its
+                # handler coroutine wakes up and closes
+                for conn in list(self._conns.values()):
+                    self._post(conn, [{"event": "shutdown", "t": self._now()}],
+                               final=True)
+                    self._conns.pop(conn.conn_id, None)
+                return True
+            if kind == "cancel":
+                sp.set_metadata(rid=cmd[1])
+                self.client.cancel(cmd[1])
+                return False
+            if kind == "submit":
+                _, payload, conn = cmd
+                try:
+                    self._submit(payload, conn)
+                except Exception as e:
+                    self._post(conn, [{"event": "error", "message": str(e)}],
+                               final=True)
+                sp.set_metadata(conn=conn.conn_id, rid=conn.handle.rid
+                                if conn.handle is not None else -1)
+                return False
             return False
-        if kind == "submit":
-            _, payload, conn = cmd
-            try:
-                self._submit(payload, conn)
-            except Exception as e:
-                self._post(conn, [{"event": "error", "message": str(e)}],
-                           final=True)
-            return False
-        return False
 
     def _submit(self, payload: Dict[str, Any], conn: _Conn) -> None:
         spec = self.config.default_spec
@@ -591,13 +616,14 @@ class ServingServer:
             self._loop.call_soon_threadsafe(self._offer, conn, None)
 
     def _flush_all(self) -> None:
-        for conn in list(self._conns.values()):
-            if conn.dead:
-                self._conns.pop(conn.conn_id, None)
-                continue
-            self._flush(conn)
-            if conn.final_sent:
-                self._conns.pop(conn.conn_id, None)
+        with span("pump.flush", conns=len(self._conns)):
+            for conn in list(self._conns.values()):
+                if conn.dead:
+                    self._conns.pop(conn.conn_id, None)
+                    continue
+                self._flush(conn)
+                if conn.final_sent:
+                    self._conns.pop(conn.conn_id, None)
 
     def _flush(self, conn: _Conn) -> None:
         h = conn.handle

@@ -121,7 +121,14 @@ differential suites run with them enabled):
 
 ``hotpath_stats()`` reports host syncs, prefill compile signatures, and
 multi-step block counts — benchmarks/engine_hotpath.py gates the speedup
-and compile-count claims on them.
+and compile-count claims on them — plus the seconds a wall-clock engine
+slept to the LatencyModel and the bucketed prefill's real and padded
+prompt tokens (exported on the server's ``GET /metrics``).
+
+The engine opens named profiler spans (``repro.obs.spans``) around each
+step, the scheduler's choice, every pacing sleep, each admission, each
+prefill bucket group, each decode dispatch and each preemption; with no
+profiler running each costs one no-op context manager.
 
 Scale substrate (PR 8): chunked prefill + paged KV
 --------------------------------------------------
@@ -230,6 +237,7 @@ from repro.core.qoe import FluidQoE
 from repro.core.scheduler import Scheduler
 from repro.models import cache as cache_lib
 from repro.models.model import Model
+from repro.obs.spans import span
 from repro.serving.kv_manager import KVSlotManager
 from repro.core.request import Request, ReqState
 from repro.serving.simulator import SimResult
@@ -354,6 +362,8 @@ class BucketedPrefill:
         self.buckets.append(min(b, cache_seq))
         self.shapes_seen = set()        # (rows, len_bucket) jit signatures
         self.on_compile = None          # optional fn(key) on new signature
+        self.tokens_real = 0            # prompt tokens prefilled
+        self.tokens_padded = 0          # rows x bucket of the device calls
         self._jit = jax.jit(self._call)
 
     def note_shape(self, key) -> None:
@@ -389,7 +399,8 @@ class BucketedPrefill:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def prefill_into(self, params, cache, slots, toks_list,
-                     frames_list=None, *, need_first=True, write=None):
+                     frames_list=None, *, need_first=True, write=None,
+                     rids=None):
         """Grouped flush: prefill every (slot, tokens) pair and scatter the
         rows into `cache` — one padded multi-row call + one fused
         `_write_slots` per bucket group (grouping BY BUCKET keeps each
@@ -401,7 +412,9 @@ class BucketedPrefill:
         device→host sync rounds performed, and the number of bucket
         groups dispatched). `write` overrides the slot-row scatter (the
         physically paged engine passes its page-pool committer; rows map
-        to slots via the same padded (N,) id array, sentinel=num_slots)."""
+        to slots via the same padded (N,) id array, sentinel=num_slots).
+        `rids`, aligned with the inputs, only label each group's
+        `prefill.group` span."""
         if write is None:
             write = lambda c, s, pad: _write_slots(c, s, jnp.asarray(pad))
         groups: dict = {}
@@ -412,19 +425,25 @@ class BucketedPrefill:
         syncs = 0
         for bucket in sorted(groups):
             idxs = groups[bucket]
-            first, src = self.run(
-                params, [toks_list[i] for i in idxs],
-                [frames_list[i] for i in idxs] if frames_list else None,
-            )
-            rows = src["length"].shape[0]
-            pad = np.full((rows,), oob, np.int32)
-            pad[: len(idxs)] = [slots[i] for i in idxs]
-            cache = write(cache, src, pad)
-            if need_first:
-                first = np.asarray(first)
-                syncs += 1
-                for j, i in enumerate(idxs):
-                    first_out[i] = first[j]
+            rows = self.row_bucket(len(idxs))
+            real = sum(len(toks_list[i]) for i in idxs)
+            self.tokens_real += real
+            self.tokens_padded += rows * bucket
+            label = " ".join(str(rids[i]) for i in idxs) if rids else ""
+            with span("prefill.group", rows=rows, bucket=bucket, real=real,
+                      rids=label):
+                first, src = self.run(
+                    params, [toks_list[i] for i in idxs],
+                    [frames_list[i] for i in idxs] if frames_list else None,
+                )
+                pad = np.full((rows,), oob, np.int32)
+                pad[: len(idxs)] = [slots[i] for i in idxs]
+                cache = write(cache, src, pad)
+                if need_first:
+                    first = np.asarray(first)
+                    syncs += 1
+                    for j, i in enumerate(idxs):
+                        first_out[i] = first[j]
         return cache, first_out, syncs, len(groups)
 
     def run(self, params, toks_list, frames_list=None):
@@ -452,6 +471,11 @@ class BucketedPrefill:
             jnp.asarray(frames) if self.enc_seq else None,
         )
         return first, cache
+
+
+def _admit_span(r: Request):
+    """The `engine.admit` span of one admission."""
+    return span("engine.admit", rid=r.rid, tokens=r.context_len)
 
 
 @dataclasses.dataclass
@@ -679,6 +703,9 @@ class ServingEngine:
         self.page_gathers = 0                # pool→contiguous row gathers (swap)
         self.page_scatters = 0               # contiguous→pool scatters (commits)
         self.page_gather_bytes = 0           # bytes moved by those gathers
+        self.pace_seconds = 0.0              # wall seconds slept in _tick
+        self._prefill.tokens_real = 0
+        self._prefill.tokens_padded = 0
         # device block tables are re-uploaded lazily: only when the page
         # assignment edition (kv.version) moved since the last upload
         self._kv_version_seen = -1
@@ -897,11 +924,15 @@ class ServingEngine:
             "page_gathers": self.page_gathers,
             "page_scatters": self.page_scatters,
             "page_gather_bytes": self.page_gather_bytes,
+            "pace_seconds_total": self.pace_seconds,
+            "prefill_tokens_real_total": self._prefill.tokens_real,
+            "prefill_tokens_padded_total": self._prefill.tokens_padded,
         }
 
     # ---------------------------------------------------------------- clock
-    def _tick(self, seconds: float) -> None:
-        """Advance the clock by one modeled operation.
+    def _tick(self, seconds: float, op: str) -> None:
+        """Advance the clock by one modeled operation of kind `op`
+        (prefill, chunk, decode, swap, overhead).
 
         Virtual: now += seconds (deterministic). Wall: the operation's
         *deadline* is now + seconds; sleep off whatever the host's real
@@ -909,6 +940,7 @@ class ServingEngine:
         the engine is paced to the LatencyModel schedule but timestamps
         carry true wall jitter. A host slower than the schedule never
         sleeps and simply drifts late (the tolerance harness measures it).
+        Each sleep is an `engine.pace` span and adds to `pace_seconds`.
         """
         if self.clock == "virtual":
             self.now += seconds
@@ -916,8 +948,11 @@ class ServingEngine:
             deadline = self.now + seconds
             w = time.monotonic() - self._wall0
             if deadline > w:
-                time.sleep(deadline - w)
-                w = time.monotonic() - self._wall0
+                with span("engine.pace", op=op, modeled_us=seconds * 1e6):
+                    time.sleep(deadline - w)
+                woke = time.monotonic() - self._wall0
+                self.pace_seconds += woke - w
+                w = woke
             self.now = w
 
     def wall_now(self) -> float:
@@ -963,7 +998,7 @@ class ServingEngine:
         toks = self._prompt_tokens(r)
         slot = self.kv.allocate(r)
         self.slot_req[slot] = r
-        self._tick(self.lat.prefill_latency(len(toks)))
+        self._tick(self.lat.prefill_latency(len(toks)), "prefill")
         if self.obs is not None:
             self.obs.prefill(r, self.now, len(toks))
         emit_t = None
@@ -1005,7 +1040,8 @@ class ServingEngine:
         step = min(self.prefill_chunk, total - r.prefill_cursor)
         r.prefill_cursor += step
         self.kv.grow(r, step)
-        self._tick(self.lat.prefill_chunk_latency(step, r.prefill_cursor))
+        self._tick(self.lat.prefill_chunk_latency(step, r.prefill_cursor),
+                   "chunk")
         if self.obs is not None:
             self.obs.prefill_chunk(r, self.now, r.prefill_cursor, total)
         prefix = toks[: r.prefill_cursor]
@@ -1043,7 +1079,7 @@ class ServingEngine:
             self.params, self.cache, slots,
             [rec.toks for rec in staged],
             [rec.frames for rec in staged],
-            write=writer,
+            write=writer, rids=[rec.req.rid for rec in staged],
         )
         self._sync(syncs)
         self._dispatch("prefill", n_groups)
@@ -1132,7 +1168,7 @@ class ServingEngine:
             self.draft.prefill(slot, toks if r.generated == 0 else toks[:-1])
             self._dispatch("draft_prefill")
             self._dispatch("write")
-        self._tick(self.lat.prefill_latency(len(toks)))
+        self._tick(self.lat.prefill_latency(len(toks)), "prefill")
         if self.obs is not None:
             self.obs.prefill(r, self.now, len(toks))
         if r.generated == 0:
@@ -1222,7 +1258,7 @@ class ServingEngine:
             # a mid-prefill victim only moves its committed prefix (the
             # cursor survives; chunking resumes after swap-in)
             self._tick(self.lat.swap_latency(
-                r.prefill_cursor or r.context_len))
+                r.prefill_cursor or r.context_len), "swap")
         else:
             self.kv.drop(r)
             r.state = ReqState.WAITING
@@ -1260,7 +1296,8 @@ class ServingEngine:
             self._dispatch("write")
         self.slot_req[slot] = r
         r.state = ReqState.RUNNING
-        self._tick(self.lat.swap_latency(r.prefill_cursor or r.context_len))
+        self._tick(self.lat.swap_latency(r.prefill_cursor or r.context_len),
+                   "swap")
         if self.obs is not None:
             self.obs.swap_in(r, self.now)
 
@@ -1415,7 +1452,7 @@ class ServingEngine:
             if rnd:
                 self.batch_sizes.append(b)
             ctx = sum(r.context_len for _slot, r in items)
-            self._tick(self.lat.iter_latency(b, ctx))
+            self._tick(self.lat.iter_latency(b, ctx), "decode")
             step_accepted = 0
             finished = False
             for slot, r in items:
@@ -1470,7 +1507,7 @@ class ServingEngine:
                                  jnp.asarray(tokens), self.cache,
                                  self.draft.cache)
             self._dispatch("spec_fused")
-            self._tick(self.lat.iter_latency(len(active), total_ctx))
+            self._tick(self.lat.iter_latency(len(active), total_ctx), "decode")
             window, greedy, accepted = jax.device_get(
                 (window, greedy, accepted)
             )
@@ -1486,7 +1523,7 @@ class ServingEngine:
             self._dispatch("verify")
             # one step's cost: k+1 draft decodes + the fused verify (the
             # SpeculativeLatencyModel's iter_latency — same call as baseline)
-            self._tick(self.lat.iter_latency(len(active), total_ctx))
+            self._tick(self.lat.iter_latency(len(active), total_ctx), "decode")
             greedy = np.asarray(jnp.argmax(logits, axis=-1))  # (slots, k+1)
             self._sync()
             accepted = None
@@ -1602,7 +1639,7 @@ class ServingEngine:
         for s in range(j):
             if s:
                 self.batch_sizes.append(b)
-            self._tick(ticks[s])
+            self._tick(ticks[s], "decode")
             finished = False
             for slot, r in items:
                 self._emit(r, int(ids[s, slot]))
@@ -1705,6 +1742,11 @@ class ServingEngine:
         (iterations are indivisible; the crossing step still overshoots)."""
         if self.stuck or not self.has_work:
             return False
+        with span("engine.step", live=len(self.live)) as sp:
+            return self._step(until, sp)
+
+    def _step(self, until: Optional[float], sp) -> bool:
+        """The body of `step`; `sp` is its `engine.step` span."""
         if not self.live and self._pending_pos < len(self._pending):
             nxt = self._pending[self._pending_pos].arrival
             if self.clock != "virtual" and nxt > self.now:
@@ -1713,7 +1755,9 @@ class ServingEngine:
                 # the admission timestamp is a genuine reading
                 w = time.monotonic() - self._wall0
                 if nxt > w:
-                    time.sleep(nxt - w)
+                    with span("engine.pace", op="arrival",
+                              modeled_us=(nxt - self.now) * 1e6):
+                        time.sleep(nxt - w)
                 self.now = max(self.now, time.monotonic() - self._wall0)
             else:
                 self.now = max(self.now, nxt)
@@ -1721,27 +1765,33 @@ class ServingEngine:
         if not self.live:
             return True
 
-        target = self.sched.schedule(self.now, self.live, self.fluid)
+        with span("sched.schedule", live=len(self.live)) as sp_sched:
+            target = self.sched.schedule(self.now, self.live, self.fluid)
+            sp_sched.set_metadata(chosen=len(target))
         target_ids = {id(r) for r in target}
 
         n_preempted = 0
         for r in list(self.slot_req.values()):
             if id(r) not in target_ids and r.state == ReqState.RUNNING:
-                self._preempt(r)
+                with span("engine.preempt", rid=r.rid,
+                          mode=self.preemption_mode):
+                    self._preempt(r)
                 n_preempted += 1
         n_admitted = 0
         staged: List[_StagedPrefill] = []
         for r in target:
             if r.state == ReqState.SWAPPED and self.kv.can_allocate(
                     r, tokens=(r.prefill_cursor or None)):
-                self._swap_in(r)
+                with _admit_span(r):
+                    self._swap_in(r)
                 n_admitted += 1
             elif r.state == ReqState.RUNNING and r.prefill_cursor:
                 # chunked prefill in flight: the resident advances one
                 # chunk per scheduled iteration, interleaved with every
                 # other slot's decode tick (it joins the decode batch
                 # only once the cursor completes)
-                staged.append(self._stage_chunk(r))
+                with _admit_span(r):
+                    staged.append(self._stage_chunk(r))
                 n_admitted += 1
             elif r.state == ReqState.WAITING:
                 if self._should_chunk(r):
@@ -1750,13 +1800,15 @@ class ServingEngine:
                     if self.kv.can_allocate(r, tokens=self.prefill_chunk):
                         r.state = ReqState.RUNNING
                         r.prefilled = True
-                        staged.append(self._stage_chunk(r))
+                        with _admit_span(r):
+                            staged.append(self._stage_chunk(r))
                         n_admitted += 1
                 elif self.kv.can_allocate(r):
                     r.state = ReqState.RUNNING
                     r.prefilled = True
                     if self._can_stage_prefill(r):
-                        staged.append(self._stage_prefill(r))
+                        with _admit_span(r):
+                            staged.append(self._stage_prefill(r))
                     else:
                         # a sequential prefill fires its emit (and possibly
                         # finish) events inline — flush what is staged first
@@ -1764,7 +1816,8 @@ class ServingEngine:
                         # path (earlier admissions report first)
                         self._flush_prefills(staged)
                         staged = []
-                        self._prefill_request(r)
+                        with _admit_span(r):
+                            self._prefill_request(r)
                     n_admitted += 1
         self._flush_prefills(staged)
 
@@ -1774,73 +1827,12 @@ class ServingEngine:
         self.batch_sizes.append(len(active))
         committed_iters = 1
         if active:
-            lengths = np.zeros(self.kv.num_slots, np.int32)
-            tokens = np.zeros(self.kv.num_slots, np.int32)
-            for s, r in active.items():
-                lengths[s] = r.context_len
-                tokens[s] = r.output_tokens[-1] if r.output_tokens else 0
-            self.cache = cache_lib.with_lengths(self.cache, lengths)
-            total_ctx = int(lengths.sum())
-            if self.spec_k:
-                s_rounds = self._spec_block_plan(active)
-                if s_rounds > 1:
-                    committed_iters = self._speculative_block(
-                        active, lengths, tokens, s_rounds, until
-                    )
-                else:
-                    self._speculative_iteration(active, lengths, tokens,
-                                                total_ctx)
-            else:
-                j = self._multi_step_plan(active, total_ctx, until)
-                if self.physical_pages:
-                    # pre-reserve every page the block will write (decode
-                    # step s writes position ctx+s): no host round-trip
-                    # can grow a table mid-block, so the whole block's
-                    # coverage must exist before dispatch. The scheduler's
-                    # paged idle_steps projection certified the demand
-                    # fits the pool. Pin the tables after.
-                    for _s, r in active.items():
-                        self.kv.ensure_pages(
-                            r, min(r.context_len + j, self._cache_seq))
-                    self._refresh_block_tables()
-                if j > 1:
-                    if self.hotpath.persistent:
-                        committed_iters = self._persistent_decode(
-                            active, tokens, total_ctx, j
-                        )
-                    else:
-                        committed_iters = self._multi_step_decode(
-                            active, tokens, total_ctx, j
-                        )
-                    if self.physical_pages:
-                        # EOS truncation / mid-block break may leave pages
-                        # reserved past the committed context — return
-                        # them to the pool (admission capacity is real now)
-                        for r in list(active.values()):
-                            if r.is_live:
-                                self.kv.trim_pages(r)
-                elif self.hotpath.fused_sampling:
-                    ids, self.cache = self._decode_tok(
-                        self.params, jnp.asarray(tokens), self.cache
-                    )
-                    self._dispatch("decode")
-                    self._tick(self.lat.iter_latency(len(active), total_ctx))
-                    nxt = np.asarray(ids)
-                    self._sync()
-                    for s, r in list(active.items()):
-                        self._emit(r, int(nxt[s]))
-                else:
-                    logits, self.cache = self._decode(
-                        self.params, jnp.asarray(tokens), self.cache
-                    )
-                    self._dispatch("decode")
-                    self._tick(self.lat.iter_latency(len(active), total_ctx))
-                    nxt = np.asarray(jnp.argmax(logits, axis=-1))
-                    self._sync()
-                    for s, r in list(active.items()):
-                        self._emit(r, int(nxt[s]))
+            with span("engine.decode", rows=len(active)) as sp_dec:
+                committed_iters = self._decode_active(active, until)
+                sp_dec.set_metadata(j=committed_iters)
         else:
-            self._tick(self.lat.hw.overhead)
+            self._tick(self.lat.hw.overhead, "overhead")
+        sp.set_metadata(admitted=n_admitted, active=len(active))
 
         self.iterations += committed_iters
         self.live = [r for r in self.live if r.is_live]
@@ -1864,6 +1856,73 @@ class ServingEngine:
             self.stuck = True                # a later submit() may clear it
             return False
         return True
+
+    def _decode_active(self, active: Dict[int, Request],
+                       until: Optional[float]) -> int:
+        """One decode dispatch over the `active` slots (single step, a
+        fused multi-step or persistent block, or speculative), through its
+        host sync and commit. Returns the iterations committed."""
+        lengths = np.zeros(self.kv.num_slots, np.int32)
+        tokens = np.zeros(self.kv.num_slots, np.int32)
+        for s, r in active.items():
+            lengths[s] = r.context_len
+            tokens[s] = r.output_tokens[-1] if r.output_tokens else 0
+        self.cache = cache_lib.with_lengths(self.cache, lengths)
+        total_ctx = int(lengths.sum())
+        if self.spec_k:
+            s_rounds = self._spec_block_plan(active)
+            if s_rounds > 1:
+                return self._speculative_block(active, lengths, tokens,
+                                               s_rounds, until)
+            self._speculative_iteration(active, lengths, tokens, total_ctx)
+            return 1
+        j = self._multi_step_plan(active, total_ctx, until)
+        if self.physical_pages:
+            # pre-reserve every page the block will write (decode
+            # step s writes position ctx+s): no host round-trip
+            # can grow a table mid-block, so the whole block's
+            # coverage must exist before dispatch. The scheduler's
+            # paged idle_steps projection certified the demand
+            # fits the pool. Pin the tables after.
+            for _s, r in active.items():
+                self.kv.ensure_pages(
+                    r, min(r.context_len + j, self._cache_seq))
+            self._refresh_block_tables()
+        if j > 1:
+            if self.hotpath.persistent:
+                committed = self._persistent_decode(active, tokens,
+                                                    total_ctx, j)
+            else:
+                committed = self._multi_step_decode(active, tokens,
+                                                    total_ctx, j)
+            if self.physical_pages:
+                # EOS truncation / mid-block break may leave pages
+                # reserved past the committed context — return
+                # them to the pool (admission capacity is real now)
+                for r in list(active.values()):
+                    if r.is_live:
+                        self.kv.trim_pages(r)
+            return committed
+        if self.hotpath.fused_sampling:
+            ids, self.cache = self._decode_tok(
+                self.params, jnp.asarray(tokens), self.cache
+            )
+            self._dispatch("decode")
+            self._tick(self.lat.iter_latency(len(active), total_ctx),
+                       "decode")
+            nxt = np.asarray(ids)
+        else:
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(tokens), self.cache
+            )
+            self._dispatch("decode")
+            self._tick(self.lat.iter_latency(len(active), total_ctx),
+                       "decode")
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        self._sync()
+        for s, r in list(active.items()):
+            self._emit(r, int(nxt[s]))
+        return 1
 
     def result(self) -> SimResult:
         return SimResult(

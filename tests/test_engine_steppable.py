@@ -125,12 +125,13 @@ def legacy_run(eng: ServingEngine, workload, max_iterations=100_000):
                 eng.params, jnp.asarray(tokens), eng.cache
             )
             total_ctx = int(lengths.sum())
-            eng._tick(eng.lat.iter_latency(len(active), total_ctx))
+            eng._tick(eng.lat.iter_latency(len(active), total_ctx),
+                      "decode")
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
             for s, r in list(active.items()):
                 eng._emit(r, int(nxt[s]))
         else:
-            eng._tick(eng.lat.hw.overhead)
+            eng._tick(eng.lat.hw.overhead, "overhead")
 
         eng.iterations += 1
         live = [r for r in live if r.is_live]

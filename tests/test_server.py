@@ -153,6 +153,34 @@ def test_metrics_prometheus_round_trip(sim_server):
     assert parsed[("connection_events_total", (("event", "open"),))] >= 1
 
 
+def test_fresh_server_attaches_no_trace_recorder():
+    """The server keeps no event trace of its own (a caller attaches a
+    TraceRecorder to the backend when it wants one): of its own it
+    attaches the MetricsObserver alone, and /metrics still round-trips."""
+    from repro.obs import MetricsObserver, MultiObserver, TraceRecorder
+
+    srv = ServingServer(ServerConfig(clock="virtual", warmup=False),
+                        backend=_sim_backend())
+    obs = srv.backend.observer
+    attached = obs.children if isinstance(obs, MultiObserver) else (obs,)
+    assert sum(isinstance(o, MetricsObserver) for o in attached) == 1
+    assert not any(isinstance(o, TraceRecorder) for o in attached)
+    assert not hasattr(srv, "trace")
+    srv.start()
+    try:
+        collect("127.0.0.1", srv.port, {"prompt_len": 6, "max_tokens": 3})
+        status, text = fetch("127.0.0.1", srv.port, "/metrics")
+        live = registry_samples_dict(srv.registry)
+    finally:
+        srv.shutdown(drain=False)
+    assert status == 200
+    parsed = parse_prometheus(text)
+    assert parsed.keys() == live.keys()
+    for k, v in live.items():
+        assert parsed[k] == pytest.approx(v, rel=1e-6, abs=1e-9), k
+    assert parsed[("requests_finished_total", ())] == 1
+
+
 def test_concurrent_streams(sim_server):
     import asyncio
 

@@ -181,16 +181,19 @@ def test_wall_clock_pacing_unit():
         def __init__(self):
             self.clock = "wall"
             self.now = 0.0
+            self.pace_seconds = 0.0
             self._wall0 = time.monotonic()
 
     e = Eng()
     for _ in range(5):
-        e._tick(0.01)
+        e._tick(0.01, "decode")
     assert e.now >= 0.05 - 1e-6          # paced: slept the modeled time
     assert e.wall_now() >= e.now - 1e-6
+    assert 0 < e.pace_seconds <= e.now   # ...and counted the sleeps
     v = Eng(); v.clock = "virtual"
-    v._tick(0.25)
+    v._tick(0.25, "decode")
     assert v.now == 0.25 and v.wall_now() == 0.25
+    assert v.pace_seconds == 0.0
 
 
 # ---------------------------------------------------------------------------
