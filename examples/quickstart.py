@@ -32,7 +32,7 @@ fairness/goodput report (the full sweep is `make bench-arena`).
 A fourth thread (PR 9): the same stack over an actual wire. `make
 serve` boots an HTTP/SSE frontend (`python -m repro.server`) whose
 `POST /v1/stream` maps this example's StreamHandle lifecycle 1:1 onto
-server-sent events, paced in real time by a `clock="wall"` engine:
+server-sent events, in real time from a `clock="wall"` engine:
 
     $ PYTHONPATH=src python -m repro.server --port 8080 &
     $ curl -N -X POST http://127.0.0.1:8080/v1/stream \

@@ -2,8 +2,9 @@
 
 Everything before PR 9 drove the serving stack in-process; this example
 is the repo's serving loop as an actual *service*. It boots a wall-clock
-`ServingServer` (the smoke-model engine paced to its LatencyModel
-schedule in real time), talks to it the way any HTTP client would, and
+`ServingServer` (the smoke-model engine in real time: paced to its
+LatencyModel schedule on the CPU, at device speed on an accelerator),
+talks to it the way any HTTP client would, and
 shows the three wire surfaces:
 
 1. `POST /v1/stream` — a prompt goes in as JSON, the response comes back

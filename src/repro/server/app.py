@@ -5,12 +5,14 @@ talks to something it does not control — a real socket, a real client,
 real time. The moving parts:
 
 * **Engine pump thread.** The backend (a `ServingEngine`, usually with
-  `clock="wall"` so its emissions happen at LatencyModel pace in real
-  time) plus its `ServingClient` live on one dedicated thread, because
-  `step()` may *sleep* to hold the schedule and must never block the
-  event loop. Commands (submit / cancel / stop) reach it through a
-  `queue.Queue`; after every step it flushes newly emitted tokens to the
-  owning connections via `loop.call_soon_threadsafe`.
+  `clock="wall"` so its emissions happen in real time: paced to the
+  LatencyModel on the CPU, at the device's own speed on an accelerator)
+  plus its `ServingClient` live on one dedicated thread, because
+  `step()` runs the device and may *sleep* (to hold the schedule, or
+  until an arrival) and must never block the event loop. Commands
+  (submit / cancel / stop) reach it through a `queue.Queue`; after
+  every step it flushes newly emitted tokens to the owning connections
+  via `loop.call_soon_threadsafe`.
 
 * **Asyncio loop thread.** A stdlib `asyncio.start_server` HTTP/1.1
   frontend (no third-party deps — CI installs none):
@@ -95,7 +97,8 @@ class ServerConfig:
     arch: str = "llama3-8b"
     full: bool = False             # published widths in bf16 (False = the
                                    # arch's smoke preset in f32)
-    clock: str = "wall"            # "wall" = real-time pacing (the point)
+    clock: str = "wall"            # "wall" = real time (the point): paced
+                                   # on the CPU, measured on an accelerator
     scheduler: str = "andes"
     num_slots: int = 4
     max_seq: int = 64
@@ -110,8 +113,9 @@ def build_engine(config: ServerConfig):
     """Construct the ServingEngine a standalone server runs: the arch's
     smoke preset in f32 or, with `config.full`, its published widths
     with bf16 weights and KV cache (f32 does not fit one 16 GB chip at
-    full width). The wall clock paces against the HardwareSpec of the
-    device JAX sees (`hardware_for_device`).
+    full width). The scheduler prices operations with the HardwareSpec of
+    the device JAX sees (`hardware_for_device`); a wall clock paces to it
+    on the CPU only.
 
     Split out so tests and the bench can build the identical engine with
     `clock="virtual"` for the differential reference."""

@@ -11,14 +11,26 @@ The clock is virtual by default (advanced by the LatencyModel per step) so
 QoE specs in seconds are meaningful on a CPU container and tests are
 deterministic.
 
-``clock="wall"`` (PR 9) runs in *real time, paced to the LatencyModel
-schedule*: every ``_tick(dt)`` sleeps off whatever part of ``dt`` the host
-computation didn't already consume, then stamps ``self.now`` with a real
-``time.monotonic()`` reading relative to ``reset()``. The engine therefore
-advances on the same schedule as the virtual clock — idle engines sleep
-until the next arrival instead of jumping the clock — but every recorded
-timestamp carries genuine OS scheduling jitter, sleep quantization, and
-whatever the host stole. Consequences, by design:
+``clock="wall"`` runs in *real time*: ``self.now`` is a
+``time.monotonic()`` reading relative to ``reset()``, and idle engines
+sleep until the next arrival instead of jumping the clock. How the time
+between readings passes depends on the device the params live on
+(``wall_clock_measures``), decided once at construction:
+
+* **Paced, on the CPU.** The CPU's HardwareSpec is a stand-in
+  (``hardware_for_device``), so the engine runs on the LatencyModel's
+  schedule: every ``_tick(dt)`` sleeps off whatever part of ``dt`` the
+  host computation didn't already consume, then stamps a reading. A CPU
+  smoke model then behaves like the chip it stands in for.
+* **Measured, on an accelerator.** The device sets its own pace:
+  ``_tick`` only takes a reading and never sleeps, and every token's time
+  is a reading taken after the host sync that delivered it (``_sync``
+  refreshes the clock; a staged prefill's first token is stamped after
+  the flush). The LatencyModel still prices operations for the
+  scheduler; it no longer sets when anything happens.
+
+Either way every recorded timestamp carries genuine OS scheduling
+jitter and whatever the host stole. Consequences, by design:
 
 * **Token text is identical** to the virtual-clock run of the same trace:
   the clock only decides *when* things happen, never *what* is computed —
@@ -26,14 +38,14 @@ whatever the host stole. Consequences, by design:
   long as admission order is preserved the emitted ids match 1:1
   (tests/test_tolerance.py pins this; the CI server smoke re-asserts it
   over a real socket).
-* **Timestamps are NOT bit-exact**, so wall-clock runs are validated by
+* **Timestamps are NOT bit-exact**, so paced runs are validated by
   the tolerance-based differential harness (repro.serving.tolerance):
   TTFT/TDS/QoE distributions must agree with the virtual reference within
   stated tolerances. If the host cannot keep up with the modeled
   schedule, the drift shows up there — that is the harness *measuring*
   the gap, not a bug in the clock.
-* The multi-step fast path is disabled (see below) and ``run()`` takes
-  real seconds: wall engines are for serving (repro.server), not sweeps.
+* ``run()`` takes real seconds: wall engines are for serving
+  (repro.server), not sweeps.
 
 The engine also serves as the oracle for validating the simulator
 (tests/test_sim_vs_engine.py): same scheduler, same workload, same latency
@@ -214,8 +226,9 @@ accelerator (``tests/test_physical_paging.py`` / ``test_persistent_loop.py``):
   committed region replays the scan bit-for-bit (the while body IS the
   scan body; rows past the certificate are discarded by the length
   gate). With ``wall_multi_step`` a wall-clock engine (the HTTP server
-  pump) runs j-step blocks too: emissions are paced per-step by `_tick`
-  as always, and a mid-block check breaks the commit early when a
+  pump) runs j-step blocks too: emissions are stamped per step by
+  `_tick` as always (paced on the CPU, all after the block's one sync
+  on an accelerator), and a mid-block check breaks the commit early when a
   pending arrival lands so admission latency stays one iteration, not j
   — timestamps there are tolerance-gated (serving/tolerance.py), token
   text identical.
@@ -242,6 +255,14 @@ from repro.serving.kv_manager import KVSlotManager
 from repro.core.request import Request, ReqState
 from repro.serving.simulator import SimResult
 from repro.serving.speculative import DraftProposer, check_speculation_compatible
+
+
+def wall_clock_measures(device) -> bool:
+    """Whether a `clock="wall"` engine whose params live on `device` (a
+    `jax.Device`) measures time rather than pacing to the LatencyModel. On
+    the CPU the HardwareSpec is a stand-in (`hardware_for_device`), so the
+    engine paces to it; an accelerator is its own clock."""
+    return device.platform != "cpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -482,11 +503,13 @@ def _admit_span(r: Request):
 class _StagedPrefill:
     """One admission whose host bookkeeping is done but whose device work
     (prefill + slot write + first-token id) is deferred to the batched
-    flush. `emit_t` is the already-ticked first-token timestamp (None for
-    recompute resumes, which emit nothing at prefill)."""
+    flush. `emits`: the prefill emits the first token (False for recompute
+    resumes). `emit_t` is its timestamp: the already-ticked time on a
+    modeled clock, None until the flush's sync on a measured one."""
     req: Request
     slot: int
     toks: np.ndarray
+    emits: bool
     emit_t: Optional[float]
     frames: Optional[np.ndarray] = None
 
@@ -536,6 +559,12 @@ class ServingEngine:
         self.lat = lat
         self.preemption_mode = preemption_mode
         self.clock = clock
+        # a wall clock measures time on an accelerator and paces to the
+        # LatencyModel on the CPU, by the device the params live on
+        self.measured = clock == "wall" and wall_clock_measures(
+            next(iter(jax.tree.leaves(params)[0].devices())))
+        if self.measured:
+            self._tick = self._read_clock
         self.eos_id = eos_id
         self.hotpath = hotpath if hotpath is not None else HotpathConfig()
         # observability (repro.obs): `self.obs` is the effective observer
@@ -756,9 +785,13 @@ class ServingEngine:
             self.draft.bucketed.on_compile = cb
 
     def _sync(self, n: int = 1) -> None:
-        """Count host<->device synchronization rounds."""
+        """Count host<->device synchronization rounds. A measured clock
+        takes a reading here, so what the sync delivered is stamped at or
+        after it."""
         if n:
             self.host_syncs += n
+            if self.measured:
+                self.now = self.wall_now()
             if self.obs is not None:
                 self.obs.sync(self.now, n)
 
@@ -934,13 +967,15 @@ class ServingEngine:
         """Advance the clock by one modeled operation of kind `op`
         (prefill, chunk, decode, swap, overhead).
 
-        Virtual: now += seconds (deterministic). Wall: the operation's
-        *deadline* is now + seconds; sleep off whatever the host's real
-        computation left of it, then stamp a real monotonic reading — so
-        the engine is paced to the LatencyModel schedule but timestamps
-        carry true wall jitter. A host slower than the schedule never
-        sleeps and simply drifts late (the tolerance harness measures it).
-        Each sleep is an `engine.pace` span and adds to `pace_seconds`.
+        Virtual: now += seconds (deterministic). Wall, paced (the CPU):
+        the operation's *deadline* is now + seconds; sleep off whatever the
+        host's real computation left of it, then stamp a real monotonic
+        reading — so the engine is paced to the LatencyModel schedule but
+        timestamps carry true wall jitter. A host slower than the schedule
+        never sleeps and simply drifts late (the tolerance harness
+        measures it). Each sleep is an `engine.pace` span and adds to
+        `pace_seconds`. Wall, measured (an accelerator): `_read_clock`
+        takes this method's place at construction.
         """
         if self.clock == "virtual":
             self.now += seconds
@@ -954,6 +989,12 @@ class ServingEngine:
                 self.pace_seconds += woke - w
                 w = woke
             self.now = w
+
+    def _read_clock(self, seconds: float, op: str) -> None:
+        """`_tick` on a measured clock: the device sets the pace, so the
+        modeled `seconds` of `op` only priced it for the scheduler; the
+        clock takes a fresh reading and nothing sleeps."""
+        self.now = self.wall_now()
 
     def wall_now(self) -> float:
         """Current time on this engine's clock for *external* events
@@ -1001,16 +1042,30 @@ class ServingEngine:
         self._tick(self.lat.prefill_latency(len(toks)), "prefill")
         if self.obs is not None:
             self.obs.prefill(r, self.now, len(toks))
-        emit_t = None
-        if r.generated == 0:
-            emit_t = self.now
+        frames = getattr(r, "frames", None) if self._prefill.enc_seq else None
+        return self._staged(r, slot, toks, frames)
+
+    def _staged(self, r: Request, slot: int, toks: np.ndarray,
+                frames) -> _StagedPrefill:
+        """Stage a prefill that completes `r`'s context, counting its first
+        token if it emits one. The token's time is `self.now` on a modeled
+        clock; a measured clock stamps it after the flush's sync
+        (`_stamp_first`)."""
+        emits = r.generated == 0
+        rec = _StagedPrefill(r, slot, toks, emits, None, frames)
+        if emits:
             r.generated = 1
-            r.emit_times.append(emit_t)
-            self.fluid.emit(r.fluid_idx, emit_t, 1)
             self.kv.grow(r)
             self.total_tokens += 1
-        frames = getattr(r, "frames", None) if self._prefill.enc_seq else None
-        return _StagedPrefill(r, slot, toks, emit_t, frames)
+            if not self.measured:
+                self._stamp_first(rec)
+        return rec
+
+    def _stamp_first(self, rec: _StagedPrefill) -> None:
+        """Record a staged prefill's first token at `self.now`."""
+        rec.emit_t = self.now
+        rec.req.emit_times.append(self.now)
+        self.fluid.emit(rec.req.fluid_idx, self.now, 1)
 
     # ------------------------------------------------------ chunked prefill
     def _should_chunk(self, r: Request) -> bool:
@@ -1045,20 +1100,13 @@ class ServingEngine:
         if self.obs is not None:
             self.obs.prefill_chunk(r, self.now, r.prefill_cursor, total)
         prefix = toks[: r.prefill_cursor]
-        emit_t = None
-        if r.prefill_cursor >= total:              # final chunk
-            r.prefill_cursor = 0
-            if self.obs is not None:
-                self.obs.prefill(r, self.now, total)
-            if r.generated == 0:
-                emit_t = self.now
-                r.generated = 1
-                r.emit_times.append(emit_t)
-                self.fluid.emit(r.fluid_idx, emit_t, 1)
-                self.kv.grow(r)
-                self.total_tokens += 1
         frames = getattr(r, "frames", None) if self._prefill.enc_seq else None
-        return _StagedPrefill(r, slot, prefix, emit_t, frames)
+        if r.prefill_cursor < total:
+            return _StagedPrefill(r, slot, prefix, False, None, frames)
+        r.prefill_cursor = 0                       # final chunk
+        if self.obs is not None:
+            self.obs.prefill(r, self.now, total)
+        return self._staged(r, slot, prefix, frames)
 
     def _flush_prefills(self, staged: List[_StagedPrefill]) -> None:
         """Run every staged admission's device work (the shared
@@ -1090,14 +1138,16 @@ class ServingEngine:
             # stage time), minus the trailing token on recompute resume
             n_draft = self.draft.prefill_batch(
                 slots,
-                [rec.toks if rec.emit_t is not None else rec.toks[:-1]
+                [rec.toks if rec.emits else rec.toks[:-1]
                  for rec in staged],
             )
             self._dispatch("draft_prefill", n_draft)
             self._dispatch("write", n_draft)
         obs = self.obs
         for i, rec in enumerate(staged):
-            if rec.emit_t is not None:
+            if rec.emits:
+                if rec.emit_t is None:
+                    self._stamp_first(rec)
                 rec.req.output_tokens.append(int(first[i]))
                 if obs is not None:
                     obs.emit(rec.req, rec.emit_t, 1)
@@ -1115,7 +1165,7 @@ class ServingEngine:
             # from multi-request flushes; MoE never reaches here)
             rec = self._stage_prefill(r)
             self._flush_prefills([rec])
-            if rec.emit_t is not None:
+            if rec.emits:
                 # replay `_emit`'s done check, which the deferred-token
                 # staging skips: the first token may finish the request
                 tok = r.output_tokens[-1]
@@ -1747,18 +1797,15 @@ class ServingEngine:
 
     def _step(self, until: Optional[float], sp) -> bool:
         """The body of `step`; `sp` is its `engine.step` span."""
+        if self.measured:
+            # a step starts now: what was submitted since the last one is due
+            self.now = self.wall_now()
         if not self.live and self._pending_pos < len(self._pending):
             nxt = self._pending[self._pending_pos].arrival
             if self.clock != "virtual" and nxt > self.now:
                 # an idle wall-clock engine waits out the gap for real
-                # (the virtual clock jumps it); re-read after the sleep so
-                # the admission timestamp is a genuine reading
-                w = time.monotonic() - self._wall0
-                if nxt > w:
-                    with span("engine.pace", op="arrival",
-                              modeled_us=(nxt - self.now) * 1e6):
-                        time.sleep(nxt - w)
-                self.now = max(self.now, time.monotonic() - self._wall0)
+                # (the virtual clock jumps it)
+                self._wait_for_arrival(nxt)
             else:
                 self.now = max(self.now, nxt)
         self._admit_arrivals()
@@ -1849,13 +1896,29 @@ class ServingEngine:
         # max_iterations; the steppable engine halts so unbounded drivers
         # (cluster drain) terminate. With arrivals still pending the
         # clock keeps advancing by the overhead tick exactly as the
-        # legacy loop did, preserving bit-for-bit admission times.
+        # legacy loop did, preserving bit-for-bit admission times; a
+        # measured clock, whose overhead tick does not sleep, waits for
+        # the next arrival instead of spinning its caller.
         if not active and not n_admitted and not n_preempted \
-                and not newly_arrived \
-                and self._pending_pos >= len(self._pending):
-            self.stuck = True                # a later submit() may clear it
-            return False
+                and not newly_arrived:
+            if self._pending_pos >= len(self._pending):
+                self.stuck = True            # a later submit() may clear it
+                return False
+            if self.measured:
+                self._wait_for_arrival(
+                    self._pending[self._pending_pos].arrival)
         return True
+
+    def _wait_for_arrival(self, t: float) -> None:
+        """Sleep (an `engine.pace` span, op=arrival) until the arrival due
+        at `t` on a wall clock, then re-read so its admission timestamp is
+        a genuine reading."""
+        w = self.wall_now()
+        if t > w:
+            with span("engine.pace", op="arrival",
+                      modeled_us=(t - self.now) * 1e6):
+                time.sleep(t - w)
+        self.now = max(self.now, self.wall_now())
 
     def _decode_active(self, active: Dict[int, Request],
                        until: Optional[float]) -> int:
