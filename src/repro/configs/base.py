@@ -34,6 +34,8 @@ class SSMConfig:
     version: int = 1                # 1 = Mamba (selective scan), 2 = Mamba2 (SSD)
     headdim: int = 64               # Mamba2 head dim
     chunk: int = 256                # Mamba2 chunk length
+    ngroups: int = 1                # Mamba2 B/C groups; heads split evenly
+    dt_min: float = 0.0             # floor on the softplus'd time step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +63,17 @@ class ModelConfig:
     # hybrid_shared_attn); everything else is an SSM block.
     hybrid_attn_every: int = 0      # 0 = not hybrid
     hybrid_shared_attn: bool = False
+    # Zamba2 wiring (a non-empty hybrid_layer_ids selects it): every layer
+    # is a Mamba-2 layer; at the hybrid ids one of `num_mem_blocks` shared
+    # attention + MLP blocks, taken in turn, reads concat(hidden, embedding)
+    # (so its attention is 2 x d_model wide, with softmax scale
+    # (head_dim / 2) ** -0.5) and its output, through a per-application
+    # linear, joins the Mamba layer's input. Each application has its own
+    # rank-`adapter_rank` adapter on the MLP's gate/up projection.
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
+    mlp_act: str = "silu"           # gate activation: "silu" | "gelu" (erf)
     # enc-dec
     num_encoder_layers: int = 0
     # sliding-window used by long-context serve variant (and zamba2 long mode)
@@ -83,10 +96,22 @@ class ModelConfig:
     def d_inner(self) -> int:
         return (self.ssm.expand * self.d_model) if self.ssm else 0
 
+    @property
+    def d_attn_in(self) -> int:
+        """Attention input width: concat(hidden, embedding) in Zamba2."""
+        return 2 * self.d_model if self.hybrid_layer_ids else self.d_model
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """Softmax scale where it is not the kernels' head_dim ** -0.5."""
+        return (self.head_dim / 2) ** -0.5 if self.hybrid_layer_ids else None
+
     def attn_layer_ids(self) -> Tuple[int, ...]:
         """Layers that carry attention (for hybrid archs)."""
         if self.kind == "ssm":
             return ()
+        if self.hybrid_layer_ids:
+            return tuple(self.hybrid_layer_ids)
         if self.hybrid_attn_every:
             return tuple(
                 i for i in range(self.num_layers)
@@ -95,7 +120,7 @@ class ModelConfig:
         return tuple(range(self.num_layers))
 
     def ssm_layer_ids(self) -> Tuple[int, ...]:
-        if self.kind == "ssm":
+        if self.kind == "ssm" or self.hybrid_layer_ids:
             return tuple(range(self.num_layers))
         if self.hybrid_attn_every:
             attn = set(self.attn_layer_ids())
@@ -126,10 +151,15 @@ class ModelConfig:
         if self.ssm.version == 2:
             nheads = self.d_inner // self.ssm.headdim
             scan = nheads * self.ssm.headdim * self.ssm.d_state
-            conv = (self.d_inner + 2 * self.ssm.d_state) * self.ssm.d_conv
+            conv = self.mamba2_conv_dim * self.ssm.d_conv
         else:
             scan = self.d_inner * self.ssm.d_state
         return n_ssm * (scan + conv) * dtype_bytes
+
+    @property
+    def mamba2_conv_dim(self) -> int:
+        """Channels of the Mamba-2 causal conv: x, then B and C per group."""
+        return self.d_inner + 2 * self.ssm.ngroups * self.ssm.d_state
 
 
 def _param_count(cfg: ModelConfig, active_only: bool) -> int:
@@ -139,12 +169,12 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
     total = embed + lm_head + d  # final norm
 
     def attn_params() -> int:
-        hd = cfg.head_dim
-        q = d * cfg.num_heads * hd
-        kv = 2 * d * cfg.num_kv_heads * hd
+        hd, din = cfg.head_dim, cfg.d_attn_in
+        q = din * cfg.num_heads * hd
+        kv = 2 * din * cfg.num_kv_heads * hd
         o = cfg.num_heads * hd * d
         bias = (cfg.num_heads + 2 * cfg.num_kv_heads) * hd if cfg.qkv_bias else 0
-        return q + kv + o + bias + 2 * d  # 2 norms per block
+        return q + kv + o + bias + din + d  # 2 norms per block
 
     def mlp_params(d_ff: int) -> int:
         return (3 if cfg.gated_mlp else 2) * d * d_ff  # SwiGLU vs GeLU
@@ -164,9 +194,10 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         di = cfg.d_inner
         if s.version == 2:
             nheads = di // s.headdim
-            in_proj = d * (2 * di + 2 * s.d_state + nheads)
-            conv = (di + 2 * s.d_state) * s.d_conv
-            extra = nheads * 2 + di  # A_log, D(per head), norm-ish
+            conv_dim = cfg.mamba2_conv_dim
+            in_proj = d * (di + conv_dim + nheads)
+            conv = conv_dim * s.d_conv + conv_dim   # weights + bias
+            extra = nheads * 3 + di  # dt_bias, A_log, D (per head), norm
         else:
             in_proj = d * 2 * di
             conv = di * s.d_conv
@@ -181,6 +212,12 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         total += n_attn * (attn_params() + moe_params())
     elif cfg.kind == "ssm":
         total += n_ssm * ssm_params()
+    elif cfg.kind == "hybrid" and cfg.hybrid_layer_ids:
+        total += n_ssm * ssm_params()
+        total += cfg.num_mem_blocks * (attn_params() + mlp_params(cfg.d_ff))
+        # per application: the gate/up adapter and the output linear
+        total += n_attn * (d * cfg.adapter_rank + cfg.adapter_rank * 2 * cfg.d_ff
+                           + d * d)
     elif cfg.kind == "hybrid":
         total += n_ssm * ssm_params()
         attn_blocks = 2 if cfg.hybrid_shared_attn else n_attn
@@ -229,6 +266,7 @@ ARCH_IDS = (
     "pixtral-12b",
     "phi3.5-moe-42b-a6.6b",
     "zamba2-2.7b",
+    "zamba2-7b",
     # the paper's own evaluation family
     "opt-66b",
 )

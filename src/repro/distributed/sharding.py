@@ -161,7 +161,7 @@ def cache_specs(mesh, cache_tree, cfg: ModelConfig) -> Dict:
         if key == "length" or key == "enc_length":
             return P(_fit(mesh, shape[0], dp))
         if key == "ssm_h":
-            # (L, B, di, N) or (L, B, NH, HD, N)
+            # (L, B, di, N) or (L, B, HD, N, NH)
             b_ax = _fit(mesh, shape[1], dp)
             inner = _fit(mesh, shape[2], "model")
             return P(*((None, b_ax, inner) + (None,) * (len(shape) - 3)))

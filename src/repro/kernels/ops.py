@@ -240,7 +240,3 @@ def _ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128):
     y = jnp.moveaxis(ys, 0, 1).reshape(bsz, s, nh, hd)
     y = y + xf * D[None, None, :, None]
     return y.astype(x.dtype)
-
-
-def ssd_step(h, x, dt, A, B, C, D):
-    return _ref.ssd_step_ref(h, x, dt, A, B, C, D)

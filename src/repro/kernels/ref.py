@@ -206,14 +206,3 @@ def ssd_ref(
     _, ys = jax.lax.scan(step, h0, xs)
     y = jnp.moveaxis(ys, 0, 1) + x.astype(jnp.float32) * D[None, None, :, None]
     return y.astype(x.dtype)
-
-
-def ssd_step_ref(h, x, dt, A, B, C, D):
-    """One decode step of the Mamba-2 recurrence.
-
-    h (B,NH,HD,N), x (B,NH,HD), dt (B,NH), A (NH,), B/C (B,N), D (NH,).
-    Returns (h', y) with y (B,NH,HD)."""
-    da = jnp.exp(dt * A[None])
-    h = da[..., None, None] * h + dt[..., None, None] * x[..., None] * B[:, None, None, :]
-    y = jnp.einsum("bhdn,bn->bhd", h, C) + x * D[None, :, None]
-    return h, y

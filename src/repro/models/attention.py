@@ -1,7 +1,9 @@
 """Attention blocks (GQA, optional QKV bias, RoPE, sliding window).
 
 Weight layout per (stacked) layer:
-  wq (d, H*hd), wk (d, KV*hd), wv (d, KV*hd), wo (H*hd, d)
+  wq (din, H*hd), wk (din, KV*hd), wv (din, KV*hd), wo (H*hd, d)
+  (din = cfg.d_attn_in: d, or 2d for Zamba2's concat(hidden, embedding);
+  hd = cfg.head_dim, which need not be d / H)
   [bq (H*hd,), bk, bv when qkv_bias]
 
 Three entry points:
@@ -20,16 +22,18 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
+from repro.kernels import ref
 from repro.models.layers import normal, rope
 
 
 def init_attn(rng, cfg: ModelConfig, dtype):
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    din = cfg.d_attn_in
     ks = jax.random.split(rng, 4)
     p = {
-        "wq": normal(ks[0], (d, h * hd), dtype=dtype),
-        "wk": normal(ks[1], (d, kv * hd), dtype=dtype),
-        "wv": normal(ks[2], (d, kv * hd), dtype=dtype),
+        "wq": normal(ks[0], (din, h * hd), dtype=dtype),
+        "wk": normal(ks[1], (din, kv * hd), dtype=dtype),
+        "wv": normal(ks[2], (din, kv * hd), dtype=dtype),
         "wo": normal(ks[3], (h * hd, d), dtype=dtype),
     }
     if cfg.qkv_bias:
@@ -80,7 +84,8 @@ def attn_train(
     q, k, v = _qkv(p, x, cfg, positions, apply_rope=(cfg.kind != "audio"),
                    kv_repeat=kv_repeat)
     o = ops.attention(
-        q, k, v, causal=causal, window=window, lengths=lengths, impl=impl
+        q, k, v, causal=causal, window=window, lengths=lengths, impl=impl,
+        sm_scale=cfg.attn_scale,
     )
     return o.reshape(b, s, -1) @ p["wo"]
 
@@ -100,7 +105,8 @@ def attn_prefill(
     q, k, v = _qkv(p, x, cfg, positions, apply_rope=(cfg.kind != "audio"),
                    kv_repeat=kv_repeat)
     o = ops.attention(
-        q, k, v, causal=True, window=window, lengths=lengths, impl=impl
+        q, k, v, causal=True, window=window, lengths=lengths, impl=impl,
+        sm_scale=cfg.attn_scale,
     )
     return o.reshape(b, s, -1) @ p["wo"], k, v
 
@@ -131,9 +137,60 @@ def attn_decode(
     k_cache = write(k_cache, k_new.astype(k_cache.dtype))
     v_cache = write(v_cache, v_new.astype(v_cache.dtype))
     o = ops.decode_attention(
-        q[:, 0], k_cache, v_cache, lengths + 1, window=window, impl=impl
+        q[:, 0], k_cache, v_cache, lengths + 1, window=window, impl=impl,
+        sm_scale=cfg.attn_scale,
     )
     return o.reshape(b, -1) @ p["wo"], k_cache, v_cache
+
+
+def attn_decode_at(
+    p, x_tok, k_all, v_all, entry: int, lengths, cfg: ModelConfig, *,
+    window: Optional[int] = None,
+    impl: str = "ref",
+    kv_repeat: int = 1,
+):
+    """`attn_decode` against entry `entry` of stacked caches k_all/v_all
+    (E, B, S, KV * hd), heads flattened into one minor axis: the new k/v
+    are written into the stacked arrays themselves, so no entry is sliced
+    out and written back whole. Returns (out (B, d), k_all', v_all')."""
+    b, d = x_tok.shape
+    pos = lengths[:, None]                                     # (B, 1)
+    q, k_new, v_new = _qkv(p, x_tok[:, None, :], cfg, pos,
+                           apply_rope=(cfg.kind != "audio"),
+                           kv_repeat=kv_repeat)
+    rows = jnp.arange(b)
+    k_all = k_all.at[entry, rows, lengths].set(
+        k_new.reshape(b, -1).astype(k_all.dtype))
+    v_all = v_all.at[entry, rows, lengths].set(
+        v_new.reshape(b, -1).astype(v_all.dtype))
+    o = _flat_decode_attention(q[:, 0], k_all[entry], v_all[entry],
+                               lengths + 1, window=window,
+                               sm_scale=cfg.attn_scale)
+    return o.reshape(b, -1) @ p["wo"], k_all, v_all
+
+
+def _flat_decode_attention(q, k, v, lengths, *, window=None, sm_scale=None):
+    """`decode_attention_ref` over caches whose heads share one minor axis,
+    k/v (B, S, KV * hd), as two plain matmuls that read each cache row as
+    it is stored: q laid block-diagonally over the heads' columns gives
+    every head's logits at once, and each head keeps its own block of the
+    probabilities times V. q (B, H, hd) -> (B, H, hd)."""
+    b, h, hd = q.shape
+    kvh = k.shape[-1] // hd
+    col = jnp.arange(kvh * hd)
+    own = (col[:, None] // hd) == (jnp.arange(h)[None, :] // (h // kvh))
+    qb = jnp.where(own[None], jnp.swapaxes(q[:, :, col % hd], 1, 2), 0)
+    scale = sm_scale if sm_scale is not None else hd ** -0.5
+    logits = jnp.einsum("bsj,bjh->bhs", k, qb).astype(jnp.float32) * scale
+    pos = jnp.arange(k.shape[1])[None, None, :]
+    mask = pos < lengths[:, None, None]
+    if window is not None:
+        mask &= pos > (lengths - 1)[:, None, None] - window
+    probs = jax.nn.softmax(jnp.where(mask, logits, ref.NEG_INF), axis=-1)
+    o = jnp.einsum("bhs,bsj->bhj", probs.astype(v.dtype), v)
+    o = o.reshape(b, h, kvh, hd)
+    return jnp.take_along_axis(
+        o, (jnp.arange(h) // (h // kvh))[None, :, None, None], axis=2)[:, :, 0]
 
 
 def attn_decode_paged(
@@ -170,7 +227,7 @@ def attn_decode_paged(
         v_new[:, 0].astype(v_pool.dtype), mode="drop")
     o = ops.paged_decode_attention(
         q[:, 0], k_pool, v_pool, block_tables, lengths + 1,
-        window=window, impl=impl,
+        window=window, impl=impl, sm_scale=cfg.attn_scale,
     )
     return o.reshape(b, -1) @ p["wo"], k_pool, v_pool
 

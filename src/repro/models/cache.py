@@ -5,8 +5,11 @@ A single pytree carries everything the decode step needs:
   length    (B,)                         valid context tokens per slot
   k, v      (L_attn, B, S_max, KV, hd)   attention archs (L_attn = number of
                                          attention *applications*: for the
-                                         shared-block hybrid this is rounds)
-  ssm_h     (L_ssm, B, ...)              Mamba scan state (f32)
+                                         shared-block hybrids, rounds or
+                                         hybrid layer ids; Zamba2's hybrid
+                                         ids keep (.., S_max, KV * hd))
+  ssm_h     (L_ssm, B, ...)              Mamba scan state (f32): Mamba-1
+                                         (di, N), Mamba-2 (HD, N, NH)
   ssm_conv  (L_ssm, B, K-1, conv_dim)    Mamba conv lookback
   cross_k/v (L_dec, B, S_enc, KV, hd)    enc-dec cross-attention memory
   enc_length (B,)                        valid encoder positions
@@ -82,8 +85,11 @@ def init_cache(
 
     n_attn = _num_attn_applications(cfg)
     if n_attn:
-        cache["k"] = arr((n_attn, batch, max_seq, kv, hd), dtype)
-        cache["v"] = arr((n_attn, batch, max_seq, kv, hd), dtype)
+        # Zamba2 keeps each position's heads as one minor axis
+        # (`attention.attn_decode_at`)
+        heads = (kv * hd,) if cfg.hybrid_layer_ids else (kv, hd)
+        cache["k"] = arr((n_attn, batch, max_seq) + heads, dtype)
+        cache["v"] = arr((n_attn, batch, max_seq) + heads, dtype)
 
     n_ssm = len(cfg.ssm_layer_ids())
     if n_ssm:
@@ -91,8 +97,9 @@ def init_cache(
         di = cfg.d_inner
         if s.version == 2:
             nh = di // s.headdim
-            cache["ssm_h"] = arr((n_ssm, batch, nh, s.headdim, s.d_state), jnp.float32)
-            conv_dim = di + 2 * s.d_state
+            cache["ssm_h"] = arr((n_ssm, batch, s.headdim, s.d_state, nh),
+                                 jnp.float32)
+            conv_dim = cfg.mamba2_conv_dim
         else:
             cache["ssm_h"] = arr((n_ssm, batch, di, s.d_state), jnp.float32)
             conv_dim = di
@@ -229,6 +236,8 @@ def supports_length_rollback(cfg: ModelConfig) -> bool:
 def _num_attn_applications(cfg: ModelConfig) -> int:
     if cfg.kind == "ssm":
         return 0
+    if cfg.hybrid_layer_ids:
+        return len(cfg.hybrid_layer_ids)
     if cfg.hybrid_attn_every:
         return cfg.num_layers // cfg.hybrid_attn_every
     return cfg.num_layers

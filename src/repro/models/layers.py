@@ -50,12 +50,22 @@ def init_mlp(rng, d_model: int, d_ff: int, gated: bool, dtype):
     return p
 
 
-def mlp_apply(p, x: jax.Array) -> jax.Array:
-    if "gate" in p:
-        h = jax.nn.silu(x @ p["gate"]) * (x @ p["up"])
-    else:
-        h = jax.nn.gelu(x @ p["up"])
-    return h @ p["down"]
+def mlp_apply(p, x: jax.Array, act: str = "silu", adapter=None) -> jax.Array:
+    """Gated MLP act(x W_gate) * (x W_up) W_down with act "silu" (SwiGLU)
+    or "gelu" (GeGLU, the exact erf form); a plain GeLU MLP when p has no
+    gate. `adapter` (a (d, r), b (r, 2 d_ff)) adds the low-rank (x a) b to
+    [gate | up]."""
+    if "gate" not in p:
+        return jax.nn.gelu(x @ p["up"]) @ p["down"]
+    gate, up = x @ p["gate"], x @ p["up"]
+    if adapter is not None:
+        a, b = adapter
+        gu = (x @ a) @ b
+        f = gate.shape[-1]
+        gate, up = gate + gu[..., :f], up + gu[..., f:]
+    g = jax.nn.gelu(gate, approximate=False) if act == "gelu" \
+        else jax.nn.silu(gate)
+    return (g * up) @ p["down"]
 
 
 # ---------------------------------------------------------------------------

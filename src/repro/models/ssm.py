@@ -3,7 +3,8 @@
 State carried per request (the SSM analogue of the KV cache — constant
 size, which changes the Andes knapsack weight, see DESIGN.md §4):
   Mamba-1: conv buffer (d_conv-1, d_inner) + scan state (d_inner, N)
-  Mamba-2: conv buffer (d_conv-1, d_inner + 2N) + scan state (NH, HD, N)
+  Mamba-2: conv buffer (d_conv-1, d_inner + 2GN) + scan state (NH, HD, N),
+           G = ngroups
 """
 from __future__ import annotations
 
@@ -170,16 +171,23 @@ def mamba1_decode(p, x_tok, state, cfg: ModelConfig):
 
 # ---------------------------------------------------------------------------
 # Mamba-2
+#
+# B and C come in `ngroups` groups of d_state; head h reads group
+# h // (heads / ngroups), and the gated RMS norm normalizes each group's
+# d_inner / ngroups channels on its own (Zamba2). One group is plain
+# Mamba-2. The mixer and its recurrence run under the named scopes
+# `zamba2.mamba` and `zamba2.ssd`, which the benchmark reads off the
+# device trace.
 # ---------------------------------------------------------------------------
 
 def init_mamba2(rng, cfg: ModelConfig, dtype):
     d, s = cfg.d_model, cfg.ssm
     di = cfg.d_inner
     nh = di // s.headdim
-    conv_dim = di + 2 * s.d_state
+    conv_dim = cfg.mamba2_conv_dim
     ks = jax.random.split(rng, 4)
     return {
-        "in_proj": normal(ks[0], (d, 2 * di + 2 * s.d_state + nh), dtype=dtype),
+        "in_proj": normal(ks[0], (d, di + conv_dim + nh), dtype=dtype),
         "conv_w": normal(ks[1], (s.d_conv, conv_dim), std=0.1, dtype=dtype),
         "conv_b": jnp.zeros((conv_dim,), dtype),
         "dt_bias": jnp.full((nh,), -2.0, dtype),
@@ -191,19 +199,72 @@ def init_mamba2(rng, cfg: ModelConfig, dtype):
 
 
 def _mamba2_split(p, x, cfg):
-    s = cfg.ssm
     di = cfg.d_inner
-    nh = di // s.headdim
+    nh = di // cfg.ssm.headdim
     zxbcdt = x @ p["in_proj"]
     z = zxbcdt[..., :di]
-    xbc = zxbcdt[..., di : di + di + 2 * s.d_state]
+    xbc = zxbcdt[..., di : di + cfg.mamba2_conv_dim]
     dt = zxbcdt[..., -nh:]
     return z, xbc, dt, nh
+
+
+def _mamba2_inputs(p, xbc_c, dt, cfg):
+    """Split the conv output into x (.., NH, HD), B and C (.., G, N), and
+    the time step: softplus(dt + bias), floored at the config's dt_min."""
+    s = cfg.ssm
+    di, gn = cfg.d_inner, s.ngroups * s.d_state
+    lead = xbc_c.shape[:-1]
+    x_in = xbc_c[..., :di].reshape(*lead, di // s.headdim, s.headdim)
+    B = xbc_c[..., di : di + gn].reshape(*lead, s.ngroups, s.d_state)
+    C = xbc_c[..., di + gn :].reshape(*lead, s.ngroups, s.d_state)
+    dt = jax.nn.softplus(dt + p["dt_bias"])
+    if s.dt_min:
+        dt = jnp.maximum(dt, s.dt_min)
+    return x_in, B, C, dt
+
+
+def _gated_norm(y, z, p, cfg):
+    """RMS norm of y * silu(z) over each group's d_inner / ngroups
+    channels, then the per-channel scale."""
+    g = cfg.ssm.ngroups
+    v = y * jax.nn.silu(z)
+    if g == 1:
+        return rms_norm(v, p["norm_scale"], cfg.norm_eps)
+    lead, di = v.shape[:-1], v.shape[-1]
+    vg = v.astype(jnp.float32).reshape(*lead, g, di // g)
+    vg = vg * jax.lax.rsqrt(jnp.mean(jnp.square(vg), -1, keepdims=True)
+                            + cfg.norm_eps)
+    return (vg.reshape(*lead, di)
+            * p["norm_scale"].astype(jnp.float32)).astype(v.dtype)
+
+
+def _per_head(bc, nh):
+    """(.., G, N) -> (.., NH, N): each group serves NH / G heads in turn."""
+    return jnp.repeat(bc, nh // bc.shape[-2], axis=-2)
+
+
+def _ssd_step(h, x, dt, A, B, C):
+    """One step of the recurrence, f32, without the D skip: h (B,HD,N,NH),
+    heads minor (64-wide HD and N would each be padded to a 128-lane tile
+    on a TPU), x (B,NH,HD), dt (B,NH), A (NH,), B/C (B,G,N). Returns
+    (h', y (B,NH,HD))."""
+    nh = x.shape[1]
+    Bh = jnp.swapaxes(_per_head(B, nh), 1, 2)[:, None]        # (B,1,N,NH)
+    Ch = jnp.swapaxes(_per_head(C, nh), 1, 2)[:, None]
+    da = jnp.exp(dt * A[None])[:, None, None, :]
+    dtx = (dt[:, None, :] * jnp.swapaxes(x, 1, 2))[:, :, None, :]
+    h = da * h + dtx * Bh
+    return h, jnp.swapaxes(jnp.sum(h * Ch, axis=2), 1, 2)
 
 
 def mamba2_apply(p, x, cfg: ModelConfig, *, impl="chunked", lengths=None,
                  return_state=False):
     """Full-sequence Mamba-2 (SSD) block; optionally returns decode state."""
+    with jax.named_scope("zamba2.mamba"):
+        return _mamba2_apply(p, x, cfg, impl, lengths, return_state)
+
+
+def _mamba2_apply(p, x, cfg, impl, lengths, return_state):
     s = cfg.ssm
     di = cfg.d_inner
     b, slen, _ = x.shape
@@ -214,24 +275,20 @@ def mamba2_apply(p, x, cfg: ModelConfig, *, impl="chunked", lengths=None,
         conv_prev = _gather_last(xbc, eff_len, p["conv_w"].shape[0] - 1)
     xbc_c, _ = _causal_conv(xbc, p["conv_w"])
     xbc_c = jax.nn.silu(xbc_c + p["conv_b"])
-    x_in = xbc_c[..., :di].reshape(b, slen, nh, s.headdim)
-    B = xbc_c[..., di : di + s.d_state]
-    C = xbc_c[..., di + s.d_state :]
-    dt = jax.nn.softplus(dt + p["dt_bias"])
+    x_in, B, C, dt = _mamba2_inputs(p, xbc_c, dt, cfg)
     if lengths is not None:
         valid = jnp.arange(slen)[None] < lengths[:, None]
         dt = jnp.where(valid[..., None], dt, 0.0)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    if return_state:
-        y, h_last = _ssd_with_state(x_in, dt, A, B, C, p["D"])
-    else:
-        y = ops.ssd(
-            x_in, dt, A, B, C, p["D"].astype(jnp.float32),
-            impl=impl, chunk=s.chunk,
-        )
-        h_last = None
-    y = y.reshape(b, slen, di)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
+    D = p["D"].astype(jnp.float32)
+    with jax.named_scope("zamba2.ssd"):
+        if return_state or s.ngroups > 1:
+            y, h_last = _ssd_with_state(x_in, dt, A, B, C, D)
+        else:
+            y = ops.ssd(x_in, dt, A, B[..., 0, :], C[..., 0, :], D,
+                        impl=impl, chunk=s.chunk)
+            h_last = None
+    y = _gated_norm(y.reshape(b, slen, di), z, p, cfg)
     out = y @ p["out_proj"]
     if return_state:
         return out, {"h": h_last, "conv": conv_prev}
@@ -239,17 +296,15 @@ def mamba2_apply(p, x, cfg: ModelConfig, *, impl="chunked", lengths=None,
 
 
 def _ssd_with_state(x, dt, A, B, C, D):
+    """The recurrence over a sequence, one token at a time, from a zero
+    state; returns (y, final state)."""
     bsz, s, nh, hd = x.shape
     n = B.shape[-1]
 
     def step(h, inp):
-        x_t, dt_t, b_t, c_t = inp
-        da = jnp.exp(dt_t * A[None])
-        h = da[..., None, None] * h + dt_t[..., None, None] * x_t[..., None] * b_t[:, None, None, :]
-        y = jnp.einsum("bhdn,bn->bhd", h, c_t)
-        return h, y
+        return _ssd_step(h, *inp[:2], A, *inp[2:])
 
-    h0 = jnp.zeros((bsz, nh, hd, n), jnp.float32)
+    h0 = jnp.zeros((bsz, hd, n, nh), jnp.float32)
     xs = tuple(
         jnp.moveaxis(t.astype(jnp.float32), 1, 0) for t in (x, dt, B, C)
     )
@@ -264,22 +319,20 @@ def mamba2_prefill(p, x, cfg: ModelConfig, lengths, *, impl="chunked"):
 
 def mamba2_decode(p, x_tok, state, cfg: ModelConfig):
     """One-token decode. x_tok (B, d)."""
-    s = cfg.ssm
-    di = cfg.d_inner
-    b = x_tok.shape[0]
-    z, xbc, dt, nh = _mamba2_split(p, x_tok[:, None, :], cfg)
-    z, xbc, dt = z[:, 0], xbc[:, 0], dt[:, 0]
-    xbc_c, conv = _conv_step(xbc, p["conv_w"], state["conv"])
-    xbc_c = jax.nn.silu(xbc_c + p["conv_b"])
-    x_in = xbc_c[..., :di].reshape(b, nh, s.headdim)
-    B = xbc_c[..., di : di + s.d_state]
-    C = xbc_c[..., di + s.d_state :]
-    dt = jax.nn.softplus(dt + p["dt_bias"])
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    h, y = ops.ssd_step(
-        state["h"], x_in.astype(jnp.float32), dt.astype(jnp.float32), A,
-        B.astype(jnp.float32), C.astype(jnp.float32), p["D"].astype(jnp.float32),
-    )
-    y = y.reshape(b, di).astype(x_tok.dtype)
-    y = rms_norm(y * jax.nn.silu(z), p["norm_scale"], cfg.norm_eps)
-    return y @ p["out_proj"], {"h": h, "conv": conv}
+    with jax.named_scope("zamba2.mamba"):
+        di = cfg.d_inner
+        b = x_tok.shape[0]
+        z, xbc, dt, nh = _mamba2_split(p, x_tok[:, None, :], cfg)
+        z, xbc, dt = z[:, 0], xbc[:, 0], dt[:, 0]
+        xbc_c, conv = _conv_step(xbc, p["conv_w"], state["conv"])
+        xbc_c = jax.nn.silu(xbc_c + p["conv_b"])
+        x_in, B, C, dt = _mamba2_inputs(p, xbc_c, dt, cfg)
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        f32 = lambda t: t.astype(jnp.float32)
+        with jax.named_scope("zamba2.ssd"):
+            h, y = _ssd_step(state["h"], f32(x_in), f32(dt), A, f32(B),
+                             f32(C))
+            y = y + f32(x_in) * f32(p["D"])[None, :, None]
+        y = y.reshape(b, di).astype(x_tok.dtype)
+        y = _gated_norm(y, z, p, cfg)
+        return y @ p["out_proj"], {"h": h, "conv": conv}

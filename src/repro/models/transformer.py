@@ -2,9 +2,16 @@
 
 Layers are *stacked* (leading axis = layer) and traversed with `lax.scan`,
 MaxText-style, so the 126-layer Llama-3-405B lowers to a compact HLO while
-the per-layer math stays identical to an unrolled loop. The hybrid
-(Zamba2-style) arch scans over "rounds": (attn_every − 1) Mamba-2 layers
-followed by one *weight-shared* attention+MLP block.
+the per-layer math stays identical to an unrolled loop. Hybrids come in
+two wirings. A config with ``hybrid_layer_ids`` is Zamba2 as published
+(``_zamba2_stack``): every layer is a Mamba-2 layer, and at each hybrid
+id one of ``num_mem_blocks`` shared attention+MLP blocks, taken in turn,
+reads concat(hidden, embedding); its output, through that application's
+linear, joins the Mamba layer's input. The gaps between hybrid ids are
+uneven, so the plain layers between them run as loops whose bounds are
+data. The older wiring (``hybrid_attn_every``, zamba2-2.7b) scans over
+"rounds": (attn_every − 1) Mamba-2 layers followed by one
+*weight-shared* residual attention+MLP block.
 
 All functions are pure; ``impl`` picks the attention/scan implementation
 ("ref" XLA for dry-run/CPU, "pallas" for TPU kernels, "chunked" for XLA
@@ -72,6 +79,27 @@ def init_params(rng, cfg: ModelConfig, dtype=jnp.float32):
         params["blocks"] = {
             "norm_scale": jnp.ones((L, d), dtype),
             "mamba": stack(lambda r: ssm_lib.init_mamba1(r, cfg, dtype), L, ks[2]),
+        }
+
+    elif cfg.kind == "hybrid" and cfg.hybrid_layer_ids:
+        M, A, r = cfg.num_mem_blocks, len(cfg.hybrid_layer_ids), cfg.adapter_rank
+        params["layers"] = {
+            "norm_scale": jnp.ones((L, d), dtype),
+            "mamba": stack(lambda r_: ssm_lib.init_mamba2(r_, cfg, dtype), L, ks[2]),
+        }
+        params["shared"] = {
+            "attn_norm_scale": jnp.ones((M, cfg.d_attn_in), dtype),
+            "attn": stack(lambda r_: attn.init_attn(r_, cfg, dtype), M, ks[3]),
+            "mlp_norm_scale": jnp.ones((M, d), dtype),
+            "mlp": stack(
+                lambda r_: init_mlp(r_, d, cfg.d_ff, True, dtype), M, ks[4]
+            ),
+        }
+        ka, kb, kl = jax.random.split(ks[5], 3)
+        params["hybrid"] = {
+            "adapter_a": normal(ka, (A, d, r), dtype=dtype),
+            "adapter_b": normal(kb, (A, r, 2 * cfg.d_ff), dtype=dtype),
+            "linear": normal(kl, (A, d, d), dtype=dtype),
         }
 
     elif cfg.kind == "hybrid":
@@ -232,6 +260,42 @@ def forward(
         )
         cache_parts = {"ssm_h": states["h"], "ssm_conv": states["conv"]}
 
+    elif cfg.kind == "hybrid" and cfg.hybrid_layer_ids and not collect_cache:
+        h = _zamba2_train(params, cfg, h, window=window, lengths=lengths,
+                          impl=impl, scan_impl=scan_impl, remat=remat)
+        auxs = jnp.zeros((1,), jnp.float32)
+        cache_parts = {}
+
+    elif cfg.kind == "hybrid" and cfg.hybrid_layer_ids:
+        def mamba(h, t, lp, _st):
+            x = rms_norm(h if t is None else h + t, lp["norm_scale"],
+                         cfg.norm_eps)
+            y, st = ssm_lib.mamba2_prefill(lp["mamba"], x, cfg, lengths,
+                                          impl=scan_impl)
+            return h + y, st
+
+        def attend(p, x, kv, app):
+            a, k, v = attn.attn_prefill(p, x, cfg, window=window,
+                                        lengths=lengths, impl=impl,
+                                        kv_repeat=kv_repeat)
+            return a, tuple(
+                jax.lax.dynamic_update_index_in_dim(
+                    c, new.reshape(b, s, -1).astype(c.dtype), app, 0)
+                for c, new in zip(kv, (k, v)))
+
+        states = {"h": jnp.zeros((cfg.num_layers, b) + _ssm_h_shape(cfg),
+                                 jnp.float32),
+                  "conv": jnp.zeros((cfg.num_layers, b, cfg.ssm.d_conv - 1,
+                                     cfg.mamba2_conv_dim), h.dtype)}
+        kv = (jnp.zeros((len(cfg.hybrid_layer_ids), b, s,
+                         cfg.num_kv_heads * kv_repeat * cfg.head_dim),
+                        h.dtype),) * 2
+        h, states, (ks, vs) = _zamba2_stack(params, cfg, h, h, mamba,
+                                            attend, states, kv)
+        auxs = jnp.zeros((1,), jnp.float32)
+        cache_parts = {"ssm_h": states["h"], "ssm_conv": states["conv"],
+                       "k": ks, "v": vs}
+
     elif cfg.kind == "hybrid":
         shared = params["shared"]
 
@@ -292,6 +356,118 @@ def forward(
     if collect_cache:
         return logits, aux, cache_parts
     return logits, aux
+
+
+# ---------------------------------------------------------------------------
+# Zamba2: Mamba-2 layers with shared blocks at the hybrid layer ids
+# ---------------------------------------------------------------------------
+
+def _ssm_h_shape(cfg: ModelConfig):
+    nh = cfg.d_inner // cfg.ssm.headdim
+    return (cfg.ssm.headdim, cfg.ssm.d_state, nh)
+
+
+def _take(tree, i):
+    """Entry i (traced) of every leaf's leading axis."""
+    return jax.tree.map(
+        lambda w: jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False), tree)
+
+
+def _shared_block(params, cfg: ModelConfig, k, h, emb, attend, kv):
+    """Application k: block k mod num_mem_blocks on concat(h, emb) —
+    RMSNorm, attention, RMSNorm, GeGLU MLP with adapter k — then linear k.
+    Returns (the Mamba layer's extra input, kv')."""
+    blk = _take(params["shared"], k % cfg.num_mem_blocks)
+    ap = _take(params["hybrid"], k)
+    with jax.named_scope("zamba2.shared"):
+        x = rms_norm(jnp.concatenate([h, emb], axis=-1),
+                     blk["attn_norm_scale"], cfg.norm_eps)
+        a, kv = attend(blk["attn"], x, kv, k)
+        x = rms_norm(a, blk["mlp_norm_scale"], cfg.norm_eps)
+        y = mlp_apply(blk["mlp"], x, cfg.mlp_act,
+                      (ap["adapter_a"], ap["adapter_b"]))
+        return y @ ap["linear"], kv
+
+
+def _zamba2_train(params, cfg: ModelConfig, h, *, window, lengths, impl,
+                  scan_impl, remat):
+    """The training forward of the Zamba2 wiring: one scan over the
+    layers, each carrying its application index (-1 at a plain layer) as
+    data, the shared block under a `lax.cond`. It keeps no cache, so it
+    needs no carried state; reverse-mode differentiable."""
+    emb = h
+    app = [-1] * cfg.num_layers
+    for k, i in enumerate(cfg.hybrid_layer_ids):
+        app[i] = k
+
+    def attend(p, x, kv, _app):
+        return attn.attn_train(p, x, cfg, window=window, lengths=lengths,
+                               impl=impl), kv
+
+    def layer(h, xs):
+        lp, k = xs
+        t = jax.lax.cond(
+            k >= 0,
+            lambda h: _shared_block(params, cfg, jnp.maximum(k, 0), h, emb,
+                                    attend, None)[0],
+            jnp.zeros_like, h)
+        x = rms_norm(h + t, lp["norm_scale"], cfg.norm_eps)
+        return h + ssm_lib.mamba2_apply(lp["mamba"], x, cfg,
+                                        impl=scan_impl, lengths=lengths), None
+
+    h, _ = jax.lax.scan(_maybe_remat(layer, remat), h,
+                        (params["layers"], jnp.asarray(app, jnp.int32)))
+    return h
+
+
+def _zamba2_stack(params, cfg: ModelConfig, h, emb, mamba, attend, states,
+                  kv, *, unroll: bool = False):
+    """Run the layers in the published Zamba2 wiring.
+
+    `mamba(h, t, layer_params, state) -> (h', state')` is one Mamba-2
+    layer with its residual (`t`, the shared block's contribution to its
+    input, or None); `attend(attn_params, x, kv, k) -> (a, kv')` is
+    application k's attention, `kv` the K/V stacks it writes (leading
+    axis = application). `states` ({"h", "conv"}, leading axis = layer)
+    and `kv` are carried and rewritten in place. Application k runs the
+    plain layers from the previous hybrid id up to hybrid id k (a loop
+    with those bounds as data), then `_shared_block` into the Mamba layer
+    at the hybrid id. The applications are a scan, or with `unroll`
+    straight-line code, where each one's index is static: it then reads
+    its block's weights and its K/V in place instead of as dynamic slices
+    that the compiler copies. The one-token decode step unrolls; a prefill
+    program (one per bucket shape) stays a scan, less than half the size.
+    Returns (h, states, kv)."""
+    def layer(i, carry, t=None):
+        h, st = carry
+        h, si = mamba(h, t, _take(params["layers"], i), _take(st, i))
+        st = jax.tree.map(
+            lambda s, v: jax.lax.dynamic_update_index_in_dim(
+                s, v.astype(s.dtype), i, 0), st, si)
+        return h, st
+
+    def application(carry, k, hid, start):
+        h, st, kv = carry
+        h, st = jax.lax.fori_loop(start, hid, layer, (h, st))
+        t, kv = _shared_block(params, cfg, k, h, emb, attend, kv)
+        return layer(hid, (h, st), t) + (kv,)
+
+    ids = cfg.hybrid_layer_ids
+    starts = (0,) + tuple(i + 1 for i in ids[:-1])
+    carry = (h, states, kv)
+    if unroll:
+        for k, (hid, start) in enumerate(zip(ids, starts)):
+            carry = application(carry, k, hid, start)
+    else:
+        carry, _ = jax.lax.scan(
+            lambda c, xs: (application(c, *xs), None), carry,
+            (jnp.arange(len(ids), dtype=jnp.int32),
+             jnp.asarray(ids, jnp.int32), jnp.asarray(starts, jnp.int32)))
+    h, states, kv = carry
+    if ids[-1] + 1 < cfg.num_layers:
+        h, states = jax.lax.fori_loop(ids[-1] + 1, cfg.num_layers, layer,
+                                      (h, states))
+    return h, states, kv
 
 
 # ---------------------------------------------------------------------------
@@ -419,6 +595,26 @@ def decode_step(
             block, h, (params["blocks"], cache["ssm_h"], cache["ssm_conv"])
         )
         cache = dict(cache, ssm_h=hs, ssm_conv=convs)
+
+    elif cfg.kind == "hybrid" and cfg.hybrid_layer_ids:
+        def mamba(h, t, lp, st):
+            x = rms_norm(h if t is None else h + t, lp["norm_scale"],
+                         cfg.norm_eps)
+            y, st = ssm_lib.mamba2_decode(lp["mamba"], x, st, cfg)
+            return h + y, st
+
+        def attend(p, x, kv, app):
+            a, kc, vc = attn.attn_decode_at(p, x, *kv, app, lengths, cfg,
+                                            window=window, impl=impl,
+                                            kv_repeat=kv_repeat)
+            return a, (kc, vc)
+
+        h, states, (ks, vs) = _zamba2_stack(
+            params, cfg, h, h, mamba, attend,
+            {"h": cache["ssm_h"], "conv": cache["ssm_conv"]},
+            (cache["k"], cache["v"]), unroll=True)
+        cache = dict(cache, ssm_h=states["h"], ssm_conv=states["conv"],
+                     k=ks, v=vs)
 
     elif cfg.kind == "hybrid":
         shared = params["shared"]

@@ -612,27 +612,47 @@ class ServingServer:
     def _post(self, conn: _Conn, batch: Optional[List[Dict[str, Any]]],
               final: bool = False) -> None:
         """Hand a batch to the loop thread (pump side)."""
-        if conn.dead or self._loop is None:
+        self._post_all([(conn, batch, final)])
+
+    def _post_all(self, posts) -> None:
+        """Hand (conn, batch, final) triples to the loop thread in one
+        wake-up (pump side): each wake-up is a chance for the loop thread
+        to take the interpreter from the pump, which then waits to get it
+        back before it can dispatch the next step."""
+        posts = [p for p in posts if not p[0].dead]
+        if not posts or self._loop is None:
             return
-        self._loop.call_soon_threadsafe(self._offer, conn, batch)
-        if final:
-            conn.final_sent = True
-            self._loop.call_soon_threadsafe(self._offer, conn, None)
+        for conn, _, final in posts:
+            conn.final_sent = conn.final_sent or final
+        self._loop.call_soon_threadsafe(self._offer_all, posts)
+
+    def _offer_all(self, posts) -> None:
+        for conn, batch, final in posts:
+            self._offer(conn, batch)
+            if final:
+                self._offer(conn, None)
 
     def _flush_all(self) -> None:
         with span("pump.flush", conns=len(self._conns)):
+            posts = []
             for conn in list(self._conns.values()):
                 if conn.dead:
                     self._conns.pop(conn.conn_id, None)
                     continue
-                self._flush(conn)
+                post = self._flush(conn)
+                if post is not None:
+                    posts.append(post)
+            self._post_all(posts)
+            for conn in list(self._conns.values()):
                 if conn.final_sent:
                     self._conns.pop(conn.conn_id, None)
 
-    def _flush(self, conn: _Conn) -> None:
+    def _flush(self, conn: _Conn):
+        """The (conn, batch, final) to post for what `conn`'s request
+        emitted since the last flush, or None."""
         h = conn.handle
         if h is None:
-            return
+            return None
         r = h.request
         batch: List[Dict[str, Any]] = conn.marks
         conn.marks = []
@@ -661,10 +681,11 @@ class ServingServer:
                           "qoe": r.final_qoe()})
             final = True
         if batch:
-            self._post(conn, batch, final=final)
-        elif final:
-            self._post(conn, None)
+            return conn, batch, final
+        if final:
             conn.final_sent = True
+            return conn, None, False
+        return None
 
 
 __all__ = ["ServerConfig", "ServingServer", "build_engine"]

@@ -654,8 +654,10 @@ class ServingEngine:
                 num_slots, self._cache_seq, enc_seq=model.enc_seq(max_seq),
                 dtype=cache_dtype
             )
-        self._decode = jax.jit(model.decode_step)
-        self._decode_tok = jax.jit(model.decode_tokens)
+        # a decode step rewrites the cache it is given: donating it lets the
+        # step update it in place instead of copying it whole into a new one
+        self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
+        self._decode_tok = jax.jit(model.decode_tokens, donate_argnums=(2,))
         self._decode_multi = jax.jit(model.decode_multi,
                                      static_argnames=("j",))
         self._decode_persist = jax.jit(model.decode_persistent,
@@ -1007,9 +1009,12 @@ class ServingEngine:
 
     # -------------------------------------------------------------- prefill
     def _prompt_tokens(self, r: Request) -> np.ndarray:
-        """The request's committed context: prompt (synthesized
+        """The tokens a prefill puts in the cache: the prompt (synthesized
         deterministically from the rid for token-less simulator-style
-        requests) plus any generated prefix (recompute resume)."""
+        requests) plus, on a recompute resume, every generated token but
+        the last — the last is the next decode step's input, which writes
+        it (the cache holds `context_len - 1` positions once a token is
+        emitted; `_decode_active`)."""
         if r.prompt_tokens is None:
             rng = np.random.default_rng(r.rid)
             r.prompt_tokens = rng.integers(
@@ -1017,7 +1022,7 @@ class ServingEngine:
             ).astype(np.int32)
         return np.concatenate([
             np.asarray(r.prompt_tokens, np.int32),
-            np.asarray(r.output_tokens[: r.generated], np.int32),
+            np.asarray(r.output_tokens[: max(r.generated - 1, 0)], np.int32),
         ])
 
     def _can_stage_prefill(self, r: Request) -> bool:
@@ -1104,6 +1109,10 @@ class ServingEngine:
         if r.prefill_cursor < total:
             return _StagedPrefill(r, slot, prefix, False, None, frames)
         r.prefill_cursor = 0                       # final chunk
+        if r.generated:
+            # a recompute resume's prefix leaves out its pending input
+            # token; charge it as every other resident's is charged
+            self.kv.grow(r)
         if self.obs is not None:
             self.obs.prefill(r, self.now, total)
         return self._staged(r, slot, prefix, frames)
@@ -1133,14 +1142,10 @@ class ServingEngine:
         self._dispatch("prefill", n_groups)
         self._dispatch("write", n_groups)
         if self.spec_k:
-            # draft invariant: committed[:-1] — the full staged context
-            # for fresh prefills (their first token was committed at
-            # stage time), minus the trailing token on recompute resume
+            # draft invariant: committed[:-1] — what the target's cache
+            # holds too (`_prompt_tokens`)
             n_draft = self.draft.prefill_batch(
-                slots,
-                [rec.toks if rec.emits else rec.toks[:-1]
-                 for rec in staged],
-            )
+                slots, [rec.toks for rec in staged])
             self._dispatch("draft_prefill", n_draft)
             self._dispatch("write", n_draft)
         obs = self.obs
@@ -1211,11 +1216,9 @@ class ServingEngine:
         self._dispatch("write")
         self.slot_req[slot] = r
         if self.spec_k:
-            # the draft holds committed[:-1] (speculative.py invariant): on a
-            # fresh prefill the first token is emitted just below, so `toks`
-            # is already that prefix; on recompute-resume drop the last
-            # committed token — it is the next proposal round's input.
-            self.draft.prefill(slot, toks if r.generated == 0 else toks[:-1])
+            # the draft holds committed[:-1] (speculative.py invariant),
+            # which is `toks`, the target's prefix too
+            self.draft.prefill(slot, toks)
             self._dispatch("draft_prefill")
             self._dispatch("write")
         self._tick(self.lat.prefill_latency(len(toks)), "prefill")
@@ -1381,11 +1384,11 @@ class ServingEngine:
         `lax.while_loop` over `s` verify rounds (multi-step INSIDE
         speculation; s is loop data, bounded by the static buffer cap).
         Each round re-pins both caches' length gates exactly as the host
-        does between single rounds — the target's valid prefix is the
-        committed context, the draft holds committed[:-1] (speculative.py
-        invariant) — then advances the committed length by accepted+1 and
-        feeds the correction/bonus token to the next round's draft. The
-        host replays the per-round windows off ONE sync."""
+        does between single rounds — both hold committed[:-1]
+        (speculative.py invariant) — then advances the committed length by
+        accepted+1 and feeds the correction/bonus token to the next
+        round's draft. The host replays the per-round windows off ONE
+        sync."""
         model, k = self.model, self.spec_k
         dmodel = self.draft.model
 
@@ -1398,7 +1401,7 @@ class ServingEngine:
 
             def body(c):
                 r, tok, ln, tc, dc, W, G, A = c
-                dc = dict(dc, length=jnp.maximum(ln - 1, 0))
+                dc = dict(dc, length=ln)
                 tc = dict(tc, length=ln)
                 props, dc = dmodel.propose_step(dparams, tok, dc, k)
                 window = jnp.concatenate([tok[:, None], props[:, :k]],
@@ -1482,10 +1485,7 @@ class ServingEngine:
         was crossed: the tail is discarded and both length gates roll the
         caches back)."""
         k = self.spec_k
-        draft_lengths = np.maximum(lengths - 1, 0).astype(np.int32)
-        self.draft.cache = cache_lib.with_lengths(
-            self.draft.cache, draft_lengths
-        )
+        self.draft.cache = cache_lib.with_lengths(self.draft.cache, lengths)
         W, G, A, self.cache, self.draft.cache = self._spec_block(
             self.params, self.draft.params, jnp.asarray(tokens),
             jnp.asarray(lengths), self.cache, self.draft.cache,
@@ -1545,13 +1545,11 @@ class ServingEngine:
         in one target pass, commit the longest greedy-matching prefix plus
         the correction/bonus token (lossless; 1..k+1 tokens per step)."""
         k = self.spec_k
-        # draft cache holds committed[:-1]; its next write goes one position
-        # below the target's (speculative.py invariant)
-        draft_lengths = np.maximum(lengths - 1, 0).astype(np.int32)
+        # both caches hold committed[:-1] (speculative.py invariant); the
+        # window's first input, the last committed token, lands at `lengths`
         if self.hotpath.fused_sampling:
-            self.draft.cache = cache_lib.with_lengths(
-                self.draft.cache, draft_lengths
-            )
+            self.draft.cache = cache_lib.with_lengths(self.draft.cache,
+                                                      lengths)
             window, greedy, accepted, self.cache, self.draft.cache = \
                 self._spec_fused(self.params, self.draft.params,
                                  jnp.asarray(tokens), self.cache,
@@ -1563,7 +1561,7 @@ class ServingEngine:
             )
             self._sync()
         else:
-            proposals = self.draft.propose(tokens, draft_lengths, k)
+            proposals = self.draft.propose(tokens, lengths, k)
             self._dispatch("propose")
             self._sync()
             window = np.concatenate([tokens[:, None], proposals], axis=1)
@@ -1589,7 +1587,7 @@ class ServingEngine:
             # logical max_seq bound: the cache slack (_cache_seq) makes
             # every window position's logits well-defined, but committed
             # context must never exceed what a baseline engine could hold
-            m_safe = max(1, self.max_seq - int(lengths[s]))
+            m_safe = max(1, self.max_seq - r.context_len)
             toks = (list(d[:a]) + [int(g[a])])[:m_safe]
             self.spec_steps += 1
             self.spec_proposed += k
@@ -1925,13 +1923,16 @@ class ServingEngine:
         """One decode dispatch over the `active` slots (single step, a
         fused multi-step or persistent block, or speculative), through its
         host sync and commit. Returns the iterations committed."""
+        # each slot's cache holds its committed context but the last token,
+        # which this step consumes and writes at position `lengths[s]`;
+        # the LatencyModel prices the step on the committed context
         lengths = np.zeros(self.kv.num_slots, np.int32)
         tokens = np.zeros(self.kv.num_slots, np.int32)
         for s, r in active.items():
-            lengths[s] = r.context_len
+            lengths[s] = r.context_len - 1
             tokens[s] = r.output_tokens[-1] if r.output_tokens else 0
         self.cache = cache_lib.with_lengths(self.cache, lengths)
-        total_ctx = int(lengths.sum())
+        total_ctx = sum(r.context_len for r in active.values())
         if self.spec_k:
             s_rounds = self._spec_block_plan(active)
             if s_rounds > 1:
@@ -1942,14 +1943,14 @@ class ServingEngine:
         j = self._multi_step_plan(active, total_ctx, until)
         if self.physical_pages:
             # pre-reserve every page the block will write (decode
-            # step s writes position ctx+s): no host round-trip
+            # step s writes position context_len - 1 + s): no host round-trip
             # can grow a table mid-block, so the whole block's
             # coverage must exist before dispatch. The scheduler's
             # paged idle_steps projection certified the demand
             # fits the pool. Pin the tables after.
             for _s, r in active.items():
                 self.kv.ensure_pages(
-                    r, min(r.context_len + j, self._cache_seq))
+                    r, min(r.context_len - 1 + j, self._cache_seq))
             self._refresh_block_tables()
         if j > 1:
             if self.hotpath.persistent:

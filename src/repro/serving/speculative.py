@@ -18,7 +18,8 @@ round:
     the draft cache's valid prefix is always committed[: context_len - 1]
 
 i.e. the draft has consumed every committed token except the last, which is
-exactly the next round's first input. Why it holds: the proposal scan
+exactly the next round's first input (the target's cache holds the same
+prefix, so both caches are pinned to one length). Why it holds: the proposal scan
 consumes k+1 inputs (the last committed token, then its own d_1..d_k — the
 extra (k+1)-th step is what makes full acceptance not a special case).
 After a tokens are accepted, the consumed inputs d_1..d_a coincide with the

@@ -167,6 +167,63 @@ def test_recompute_resumes_token_exact(llama):
     assert eng.kv.tokens_used == 0           # everything released
 
 
+def _decode_logits(eng):
+    """rid -> the logits row of each of its decode steps (the engine's
+    host-argmax path)."""
+    rows, decode, emit, last = {}, eng._decode, eng._emit, {}
+
+    def record_decode(*args):
+        last["logits"], cache = decode(*args)
+        return last["logits"], cache
+
+    def record_emit(r, tok):
+        if "logits" in last:
+            rows.setdefault(r.rid, []).append(
+                np.asarray(last.pop("logits")[r.engine_slot]))
+        emit(r, tok)
+
+    eng._decode, eng._emit = record_decode, record_emit
+    return rows
+
+
+def test_recompute_resumes_logit_exact_hybrid():
+    """A recompute resume of the Zamba2 hybrid re-prefills the prompt and
+    every generated token but the last, which the next decode step
+    consumes: its decode logits are the uncontended run's. The recurrent
+    state, unlike K/V, would count a token prefilled and decoded again
+    twice. Tolerance 1e-4 of the logit scale: prefill and decode round
+    differently in f32 (~1e-6)."""
+    from repro.serving import HotpathConfig
+
+    cfg = get_smoke_config("zamba2-7b")
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    lat = LatencyModel(cfg, TPU_V5E)
+    host = HotpathConfig(fused_sampling=False, multi_step=1)
+    rng = np.random.default_rng(3)
+    ref = mk_req(cfg, rng)
+    ref_eng = ServingEngine(m, params, make_scheduler("fcfs", 10_000, lat),
+                            lat, num_slots=4, max_seq=64, hotpath=host)
+    want = _decode_logits(ref_eng)
+    ref_eng.run([ref], max_iterations=100)
+
+    eng = ServingEngine(m, params, make_scheduler("fcfs", 10_000, lat), lat,
+                        num_slots=4, max_seq=64, preemption_mode="recompute",
+                        hotpath=host)
+    got = _decode_logits(eng)
+    r = Request(rid=ref.rid, arrival=0.0, prompt_len=ref.prompt_len,
+                output_len=ref.output_len, spec=ref.spec,
+                prompt_tokens=ref.prompt_tokens)
+    start_running(eng, r, steps=3)
+    eng._preempt(r)
+    while eng.step():
+        pass
+    assert r.output_tokens == ref.output_tokens
+    assert eng.kv.tokens_used == 0
+    a, b = np.stack(got[r.rid]), np.stack(want[ref.rid])
+    np.testing.assert_allclose(a, b, rtol=0, atol=1e-4 * np.abs(b).max())
+
+
 # ---------------------------------------------------------------------------
 # Preemption under speculation: both caches round-trip, mid-proposal
 # ---------------------------------------------------------------------------

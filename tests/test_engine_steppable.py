@@ -118,13 +118,13 @@ def legacy_run(eng: ServingEngine, workload, max_iterations=100_000):
             lengths = np.zeros(eng.kv.num_slots, np.int32)
             tokens = np.zeros(eng.kv.num_slots, np.int32)
             for s, r in active.items():
-                lengths[s] = r.context_len
+                lengths[s] = r.context_len - 1   # the cache's positions
                 tokens[s] = r.output_tokens[-1] if r.output_tokens else 0
             eng.cache["length"] = jnp.asarray(lengths)
             logits, eng.cache = eng._decode(
                 eng.params, jnp.asarray(tokens), eng.cache
             )
-            total_ctx = int(lengths.sum())
+            total_ctx = sum(r.context_len for r in active.values())
             eng._tick(eng.lat.iter_latency(len(active), total_ctx),
                       "decode")
             nxt = np.asarray(jnp.argmax(logits, axis=-1))

@@ -176,7 +176,8 @@ def _prefill_property(arch, *, batch_rows):
         assert int(np.argmax(padded[i])) == int(np.argmax(exact[i])), l
 
 
-@pytest.mark.parametrize("arch", ["llama3-8b", "falcon-mamba-7b"])
+@pytest.mark.parametrize("arch", ["llama3-8b", "falcon-mamba-7b",
+                                  "zamba2-7b"])
 def test_bucketed_prefill_matches_exact(arch):
     _prefill_property(arch, batch_rows=True)
 

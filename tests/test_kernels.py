@@ -95,6 +95,29 @@ def test_decode_attention_window():
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), atol=2e-5)
 
 
+@pytest.mark.parametrize("h,kv,window", [
+    (4, 4, None),            # MHA, as Zamba2's shared blocks
+    (8, 2, None),            # GQA
+    (8, 2, 32),
+])
+def test_flat_decode_attention_matches_ref(h, kv, window):
+    """Decode attention over caches whose heads share one minor axis
+    (models/attention.py, Zamba2's K/V) == the reference over
+    (B, S, KV, hd), ragged lengths included."""
+    from repro.models.attention import _flat_decode_attention
+    b, s, hd = 3, 96, 24
+    q = rand(0, (b, h, hd), jnp.float32)
+    k = rand(1, (b, s, kv, hd), jnp.float32)
+    v = rand(2, (b, s, kv, hd), jnp.float32)
+    lengths = jnp.array([96, 40, 1], jnp.int32)
+    out = _flat_decode_attention(q, k.reshape(b, s, -1), v.reshape(b, s, -1),
+                                 lengths, window=window, sm_scale=0.3)
+    expect = ref.decode_attention_ref(q, k, v, lengths, window=window,
+                                      sm_scale=0.3)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # selective scan (Mamba-1)
 # ---------------------------------------------------------------------------

@@ -3,9 +3,9 @@
 The TPU compiler is installed with jaxlib and compiles for a topology that
 is described rather than present. These tests compile the main path's
 Pallas kernels at real widths (each must lower to a `tpu_custom_call`),
-and granite-3-2b's full-width bf16 decode step and the serving engine's
-largest prefill, whose bytes must fit one 16 GB chip. Nothing runs, so
-they say nothing about results or times.
+and granite-3-2b's and zamba2-7b's full-width bf16 decode steps and the
+serving engine's largest prefills, whose bytes must fit one 16 GB chip.
+Nothing runs, so they say nothing about results or times.
 
 The topology, and everything built from it, is made inside module-scoped
 fixtures of this file: only the process that runs these tests loads the
@@ -124,30 +124,58 @@ def granite(one_chip):
     return model, _place(one_chip, model.abstract_params())
 
 
+@pytest.fixture(scope="module")
+def zamba2(one_chip):
+    """zamba2-7b (24 of 81 layers) at its published widths in bf16."""
+    model = Model(get_config("zamba2-7b"), param_dtype=jnp.bfloat16)
+    return model, _place(one_chip, model.abstract_params())
+
+
 def _device_bytes(compiled):
     m = compiled.memory_analysis()
     return m.argument_size_in_bytes + m.temp_size_in_bytes
 
 
+def _decode_step(model, params, sh, slots=FULL_SLOTS, max_seq=FULL_MAX_SEQ):
+    cache = _place(sh, model.init_cache(
+        slots, max_seq, dtype=jnp.bfloat16, abstract=True))
+    tokens = _spec(sh, (slots,), jnp.int32)
+    return jax.jit(model.decode_tokens).lower(params, tokens, cache).compile()
+
+
+def _largest_prefill(model, params, sh, slots=FULL_SLOTS,
+                     max_seq=FULL_MAX_SEQ):
+    """The largest prefill an engine of `slots` x `max_seq` can form:
+    every slot a row, each padded to the terminal length bucket."""
+    bp = BucketedPrefill(model, max_seq, jnp.bfloat16, max_seq=max_seq)
+    rows, seq = bp.row_bucket(slots), bp.buckets[-1]
+    assert seq == max_seq
+    return jax.jit(bp._call).lower(
+        params, _spec(sh, (rows, seq), jnp.int32),
+        _spec(sh, (rows,), jnp.int32), None).compile()
+
+
 def test_full_width_decode_step_fits_one_chip(granite, one_chip):
-    model, params = granite
-    cache = _place(one_chip, model.init_cache(
-        FULL_SLOTS, FULL_MAX_SEQ, dtype=jnp.bfloat16, abstract=True))
-    tokens = _spec(one_chip, (FULL_SLOTS,), jnp.int32)
-    compiled = jax.jit(model.decode_tokens).lower(params, tokens,
-                                                  cache).compile()
-    assert _device_bytes(compiled) < HBM_BYTES
+    assert _device_bytes(_decode_step(*granite, one_chip)) < HBM_BYTES
 
 
 def test_largest_engine_prefill_fits_one_chip(granite, one_chip):
-    """The largest prefill the full server's engine can form: every slot
-    a row, each padded to the terminal length bucket."""
-    model, params = granite
-    bp = BucketedPrefill(model, FULL_MAX_SEQ, jnp.bfloat16,
-                         max_seq=FULL_MAX_SEQ)
-    rows, seq = bp.row_bucket(FULL_SLOTS), bp.buckets[-1]
-    assert seq == FULL_MAX_SEQ
-    compiled = jax.jit(bp._call).lower(
-        params, _spec(one_chip, (rows, seq), jnp.int32),
-        _spec(one_chip, (rows,), jnp.int32), None).compile()
+    assert _device_bytes(_largest_prefill(*granite, one_chip)) < HBM_BYTES
+
+
+# the benchmark's zamba2-7b engine (bench/configs/zamba2-7b.json, whose
+# `reckoning` holds these compiles' bytes)
+ZAMBA2_SLOTS, ZAMBA2_MAX_SEQ = 16, 1024
+
+
+def test_zamba2_decode_step_fits_one_chip(zamba2, one_chip):
+    """The hybrid's decode step: weights, the four applications' K/V and
+    every layer's recurrent state for 16 slots of 1024."""
+    compiled = _decode_step(*zamba2, one_chip, ZAMBA2_SLOTS, ZAMBA2_MAX_SEQ)
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_zamba2_largest_engine_prefill_fits_one_chip(zamba2, one_chip):
+    compiled = _largest_prefill(*zamba2, one_chip, ZAMBA2_SLOTS,
+                                ZAMBA2_MAX_SEQ)
     assert _device_bytes(compiled) < HBM_BYTES
