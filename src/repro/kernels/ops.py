@@ -171,72 +171,3 @@ def _selective_scan_chunked(x, dt, A, B, C, D, *, chunk: int = 128):
 def selective_scan_step(h, x, dt, A, B, C, D):
     """Decode-step recurrence (always XLA; it is a handful of elementwise ops)."""
     return _ref.selective_scan_step_ref(h, x, dt, A, B, C, D)
-
-
-# ---------------------------------------------------------------------------
-# Mamba-2 SSD
-# ---------------------------------------------------------------------------
-
-def ssd(x, dt, A, B, C, D, *, impl: str = DEFAULT_IMPL, chunk: int = 128):
-    """Mamba-2 scan. x (B,S,NH,HD), dt (B,S,NH), A (NH,), B/C (B,S,N), D (NH,)."""
-    if impl in ("chunked", "pallas"):
-        # The SSD chunked form is already matmul-dominant; on TPU it lowers to
-        # MXU einsums directly, so the XLA chunked form *is* the TPU-native
-        # kernelization (no Pallas needed — noted in DESIGN.md).
-        return _ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
-    return _ref.ssd_ref(x, dt, A, B, C, D)
-
-
-def _ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128):
-    """Chunked state-space-dual algorithm (Mamba-2), pure XLA.
-
-    Intra-chunk: quadratic attention-like masked einsum (MXU-friendly).
-    Inter-chunk: scan over chunk boundary states (B, NH, HD, N).
-    """
-    bsz, s, nh, hd = x.shape
-    n = B.shape[-1]
-    while s % chunk:
-        chunk //= 2
-    nchunks = s // chunk
-
-    xf = x.astype(jnp.float32)
-    dtf = dt.astype(jnp.float32)
-    bf = B.astype(jnp.float32)
-    cf = C.astype(jnp.float32)
-
-    def to_chunks(t):
-        return jnp.moveaxis(t.reshape(bsz, nchunks, chunk, *t.shape[2:]), 1, 0)
-
-    xc, dtc, bc, cc = map(to_chunks, (xf, dtf, bf, cf))
-
-    def chunk_step(h0, inputs):
-        xk, dtk, bk, ck = inputs
-        # log decay within chunk: la[t] = sum_{u<=t} dt_u * A   (B, chunk, NH)
-        da = dtk * A[None, None]                       # (B, chunk, NH) (<=0)
-        la = jnp.cumsum(da, axis=1)
-        # intra-chunk "attention" scores: decay from u to t (u<=t)
-        # L[t,u] = exp(la_t - la_u) for u<=t else 0
-        diff = la[:, :, None, :] - la[:, None, :, :]   # (B, t, u, NH)
-        tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-        l_mat = jnp.where(tri[None, :, :, None], jnp.exp(diff), 0.0)
-        cb = jnp.einsum("btn,bun->btu", ck, bk)        # (B, t, u)
-        w = cb[..., None] * l_mat * dtk[:, None, :, :]  # (B, t, u, NH)
-        y_intra = jnp.einsum("btuh,buhd->bthd", w, xk)
-        # contribution of the carried state
-        decay0 = jnp.exp(la)                            # (B, t, NH)
-        y_carry = jnp.einsum(
-            "btn,bhdn,bth->bthd", ck, h0, decay0
-        )
-        # new boundary state
-        decay_to_end = jnp.exp(la[:, -1:, :] - la)      # (B, u, NH)
-        h_upd = jnp.einsum(
-            "bun,buhd,buh->bhdn", bk, xk * dtk[..., None], decay_to_end
-        )
-        h_next = jnp.exp(la[:, -1])[..., None, None] * h0 + h_upd
-        return h_next, y_intra + y_carry
-
-    h0 = jnp.zeros((bsz, nh, hd, n), jnp.float32)
-    _, ys = jax.lax.scan(chunk_step, h0, (xc, dtc, bc, cc))
-    y = jnp.moveaxis(ys, 0, 1).reshape(bsz, s, nh, hd)
-    y = y + xf * D[None, None, :, None]
-    return y.astype(x.dtype)

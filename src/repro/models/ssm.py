@@ -8,8 +8,11 @@ size, which changes the Andes knapsack weight, see DESIGN.md §4):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops
@@ -257,15 +260,93 @@ def _ssd_step(h, x, dt, A, B, C):
     return h, jnp.swapaxes(jnp.sum(h * Ch, axis=2), 1, 2)
 
 
-def mamba2_apply(p, x, cfg: ModelConfig, *, impl="chunked", lengths=None,
-                 return_state=False):
+# One chunk's decay matrix is (rows, chunk, chunk, heads) f32; rows x
+# chunk**2 at most this holds it to 117 MB at 112 heads, no more than a
+# 1024-token prefill's per-token f32 outputs at 4 rows or more (chunks of
+# 256 for up to 4 rows, 128 for 16).
+_SSD_CHUNK_AREA = 1 << 18
+
+
+def ssd_chunk(cfg: ModelConfig, rows: int, seq: int) -> int:
+    """Chunk length of the chunked SSD for `rows` sequences of `seq`
+    tokens: 0 for a model without Mamba-2 layers. The cap is the config's
+    chunk, halved while rows x cap**2 > _SSD_CHUNK_AREA; the sequence
+    splits into the fewest chunks under the cap, of equal length, so a
+    power-of-two bucket runs chunks of exactly the cap and any other
+    length pads fewer tokens than it has chunks."""
+    if cfg.kind != "hybrid":
+        return 0
+    cap = cfg.ssm.chunk
+    while cap > 1 and rows * cap * cap > _SSD_CHUNK_AREA:
+        cap //= 2
+    n = -(-seq // cap)
+    return -(-seq // n)
+
+
+def _ssd_chunked(x, dt, A, B, C, D, chunk):
+    """The recurrence over a sequence in the chunked state-space-dual
+    form (Mamba-2), from a zero state. x (B,S,NH,HD), dt (B,S,NH), A and
+    D (NH,), B/C (B,S,G,N); head h reads group h // (NH/G). The sequence
+    is zero-padded to whole chunks (dt = 0: the pad leaves the state as
+    it is). In a chunk, outputs are masked matmuls over its tokens plus
+    the carried state's read-out; a loop carries the state from chunk to
+    chunk and writes each chunk's outputs in place. Decays, state and
+    accumulation are f32. Returns (y (B,S,NH,HD) in x's dtype, the final
+    state (B,HD,N,NH) as `_ssd_step` takes it)."""
+    bsz, s, nh, hd = x.shape
+    g, n = B.shape[-2:]
+    r = nh // g
+    nc = -(-s // chunk)
+    pad = [(0, 0), (0, nc * chunk - s)]
+    x = jnp.pad(x, pad + [(0, 0)] * 2).reshape(bsz, -1, g, r, hd)
+    dt = jnp.pad(dt, pad + [(0, 0)]).reshape(bsz, -1, g, r)
+    B, C = (jnp.pad(t, pad + [(0, 0)] * 2) for t in (B, C))
+    ag, dg = A.reshape(g, r), D.reshape(g, r, 1)
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+
+    def body(c, carry):     # h (B,HD,N,G,R)
+        h, y = carry
+        xk, dtk, bk, ck = (
+            jax.lax.dynamic_slice_in_dim(t, c * chunk, chunk, 1)
+            .astype(jnp.float32) for t in (x, dt, B, C))
+        # (B,Q,G,R,HD) (B,Q,G,R) (B,Q,G,N) (B,Q,G,N)
+        la = jnp.cumsum(dtk * ag, axis=1)               # (B,Q,G,R), <= 0
+        lt = jnp.moveaxis(la, 1, -1)                    # (B,G,R,Q)
+        # decay from token u to token t >= u, exp(la_t - la_u); -inf above
+        # the diagonal, so that the exp never overflows
+        seg = jnp.where(causal, lt[..., :, None] - lt[..., None, :], -jnp.inf)
+        w = (dot("btgn,bugn->bgtu", ck, bk)[:, :, None] * jnp.exp(seg)
+             * jnp.moveaxis(dtk, 1, -1)[..., None, :])  # (B,G,R,t,u)
+        yk = (dot("bgrtu,bugrd->btgrd", w, xk)
+              + dot("btgn,bdngr->btgrd", ck, h) * jnp.exp(la)[..., None]
+              + xk * dg)
+        to_end = (jnp.exp(la[:, -1:] - la) * dtk)[..., None]
+        h = (jnp.exp(la[:, -1])[:, None, None] * h
+             + dot("bugn,bugrd->bdngr", bk, xk * to_end))
+        y = jax.lax.dynamic_update_slice_in_dim(y, yk.astype(y.dtype),
+                                                c * chunk, 1)
+        return h, y
+
+    h, y = jax.lax.fori_loop(
+        0, nc, body,
+        (jnp.zeros((bsz, hd, n, g, r), jnp.float32), jnp.zeros_like(x)))
+    # the state leaves in the row-major layout the decode step reads; left
+    # free, the compiler gives the stack of every layer's state the
+    # matmuls' layout (N minor, padded 64 -> 128) and copies it back at the
+    # end, 1.4 GB more at 16 rows
+    h = with_layout_constraint(h.reshape(bsz, hd, n, nh),
+                               Layout(major_to_minor=(0, 1, 2, 3)))
+    return y.reshape(bsz, -1, nh, hd)[:, :s], h
+
+
+def mamba2_apply(p, x, cfg: ModelConfig, *, lengths=None, return_state=False):
     """Full-sequence Mamba-2 (SSD) block; optionally returns decode state."""
     with jax.named_scope("zamba2.mamba"):
-        return _mamba2_apply(p, x, cfg, impl, lengths, return_state)
+        return _mamba2_apply(p, x, cfg, lengths, return_state)
 
 
-def _mamba2_apply(p, x, cfg, impl, lengths, return_state):
-    s = cfg.ssm
+def _mamba2_apply(p, x, cfg, lengths, return_state):
     di = cfg.d_inner
     b, slen, _ = x.shape
     z, xbc, dt, nh = _mamba2_split(p, x, cfg)
@@ -282,12 +363,8 @@ def _mamba2_apply(p, x, cfg, impl, lengths, return_state):
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     D = p["D"].astype(jnp.float32)
     with jax.named_scope("zamba2.ssd"):
-        if return_state or s.ngroups > 1:
-            y, h_last = _ssd_with_state(x_in, dt, A, B, C, D)
-        else:
-            y = ops.ssd(x_in, dt, A, B[..., 0, :], C[..., 0, :], D,
-                        impl=impl, chunk=s.chunk)
-            h_last = None
+        y, h_last = _ssd_chunked(x_in, dt, A, B, C, D,
+                                 ssd_chunk(cfg, b, slen))
     y = _gated_norm(y.reshape(b, slen, di), z, p, cfg)
     out = y @ p["out_proj"]
     if return_state:
@@ -295,26 +372,8 @@ def _mamba2_apply(p, x, cfg, impl, lengths, return_state):
     return out
 
 
-def _ssd_with_state(x, dt, A, B, C, D):
-    """The recurrence over a sequence, one token at a time, from a zero
-    state; returns (y, final state)."""
-    bsz, s, nh, hd = x.shape
-    n = B.shape[-1]
-
-    def step(h, inp):
-        return _ssd_step(h, *inp[:2], A, *inp[2:])
-
-    h0 = jnp.zeros((bsz, hd, n, nh), jnp.float32)
-    xs = tuple(
-        jnp.moveaxis(t.astype(jnp.float32), 1, 0) for t in (x, dt, B, C)
-    )
-    h_last, ys = jax.lax.scan(step, h0, xs)
-    y = jnp.moveaxis(ys, 0, 1) + x.astype(jnp.float32) * D[None, None, :, None]
-    return y.astype(x.dtype), h_last
-
-
-def mamba2_prefill(p, x, cfg: ModelConfig, lengths, *, impl="chunked"):
-    return mamba2_apply(p, x, cfg, impl=impl, lengths=lengths, return_state=True)
+def mamba2_prefill(p, x, cfg: ModelConfig, lengths):
+    return mamba2_apply(p, x, cfg, lengths=lengths, return_state=True)
 
 
 def mamba2_decode(p, x_tok, state, cfg: ModelConfig):

@@ -262,7 +262,7 @@ def forward(
 
     elif cfg.kind == "hybrid" and cfg.hybrid_layer_ids and not collect_cache:
         h = _zamba2_train(params, cfg, h, window=window, lengths=lengths,
-                          impl=impl, scan_impl=scan_impl, remat=remat)
+                          impl=impl, remat=remat)
         auxs = jnp.zeros((1,), jnp.float32)
         cache_parts = {}
 
@@ -270,8 +270,7 @@ def forward(
         def mamba(h, t, lp, _st):
             x = rms_norm(h if t is None else h + t, lp["norm_scale"],
                          cfg.norm_eps)
-            y, st = ssm_lib.mamba2_prefill(lp["mamba"], x, cfg, lengths,
-                                          impl=scan_impl)
+            y, st = ssm_lib.mamba2_prefill(lp["mamba"], x, cfg, lengths)
             return h + y, st
 
         def attend(p, x, kv, app):
@@ -318,9 +317,9 @@ def forward(
         def mamba_layer(h, lp):
             x = rms_norm(h, lp["norm_scale"], cfg.norm_eps)
             if collect_cache:
-                y, st = ssm_lib.mamba2_prefill(lp["mamba"], x, cfg, lengths, impl=scan_impl)
+                y, st = ssm_lib.mamba2_prefill(lp["mamba"], x, cfg, lengths)
             else:
-                y = ssm_lib.mamba2_apply(lp["mamba"], x, cfg, impl=scan_impl)
+                y = ssm_lib.mamba2_apply(lp["mamba"], x, cfg)
                 st = {"h": jnp.zeros((), jnp.float32), "conv": jnp.zeros((), h.dtype)}
             return h + y, st
 
@@ -390,7 +389,7 @@ def _shared_block(params, cfg: ModelConfig, k, h, emb, attend, kv):
 
 
 def _zamba2_train(params, cfg: ModelConfig, h, *, window, lengths, impl,
-                  scan_impl, remat):
+                  remat):
     """The training forward of the Zamba2 wiring: one scan over the
     layers, each carrying its application index (-1 at a plain layer) as
     data, the shared block under a `lax.cond`. It keeps no cache, so it
@@ -413,7 +412,7 @@ def _zamba2_train(params, cfg: ModelConfig, h, *, window, lengths, impl,
             jnp.zeros_like, h)
         x = rms_norm(h + t, lp["norm_scale"], cfg.norm_eps)
         return h + ssm_lib.mamba2_apply(lp["mamba"], x, cfg,
-                                        impl=scan_impl, lengths=lengths), None
+                                        lengths=lengths), None
 
     h, _ = jax.lax.scan(_maybe_remat(layer, remat), h,
                         (params["layers"], jnp.asarray(app, jnp.int32)))

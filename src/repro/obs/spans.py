@@ -46,7 +46,8 @@ SPAN_NAMES = {
                     "eager prefill, or swap-in (rid, tokens)",
     "prefill.group": "one bucket group of a prefill flush: device call, "
                      "slot write, first-token read (rows, bucket, real, "
-                     "rids)",
+                     "rids, ssd_chunk: the chunk its Mamba-2 layers ran "
+                     "with, 0 without them)",
     "engine.decode": "one decode dispatch through its host sync and "
                      "commit, of any kind (rows; j: iterations committed)",
     "engine.preempt": "one preemption (rid, mode)",

@@ -131,7 +131,8 @@ differential suites run with them enabled):
   (``clock="wall"``) cannot reconstruct per-step timestamps and always
   single-step.
 
-``hotpath_stats()`` reports host syncs, prefill compile signatures, and
+``hotpath_stats()`` reports host syncs, prefill compile signatures (with
+the chunk length each one's Mamba-2 layers ran with), and
 multi-step block counts — benchmarks/engine_hotpath.py gates the speedup
 and compile-count claims on them — plus the seconds a wall-clock engine
 slept to the LatencyModel and the bucketed prefill's real and padded
@@ -250,6 +251,7 @@ from repro.core.qoe import FluidQoE
 from repro.core.scheduler import Scheduler
 from repro.models import cache as cache_lib
 from repro.models.model import Model
+from repro.models.ssm import ssd_chunk
 from repro.obs.spans import span
 from repro.serving.kv_manager import KVSlotManager
 from repro.core.request import Request, ReqState
@@ -452,7 +454,8 @@ class BucketedPrefill:
             self.tokens_padded += rows * bucket
             label = " ".join(str(rids[i]) for i in idxs) if rids else ""
             with span("prefill.group", rows=rows, bucket=bucket, real=real,
-                      rids=label):
+                      rids=label,
+                      ssd_chunk=ssd_chunk(self.model.cfg, rows, bucket)):
                 first, src = self.run(
                     params, [toks_list[i] for i in idxs],
                     [frames_list[i] for i in idxs] if frames_list else None,
@@ -951,6 +954,9 @@ class ServingEngine:
             "dispatches": self.dispatches,
             "prefill_shapes": sorted(shapes),
             "prefill_compiles": len(shapes),
+            # (rows, length) -> the chunk its Mamba-2 layers ran with
+            "ssd_chunks": {k: ssd_chunk(self.model.cfg, *k)
+                           for k in sorted(self._prefill.shapes_seen)},
             "prefill_bucket_grid": list(self._prefill.buckets),
             "multi_step_blocks": self.multi_step_blocks,
             "multi_step_iters": self.multi_step_iters,

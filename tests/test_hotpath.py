@@ -192,6 +192,29 @@ def test_bucketed_prefill_matches_exact_all_kinds(arch):
     _prefill_property(arch, batch_rows=True)
 
 
+@pytest.mark.parametrize("arch,chunk", [("zamba2-7b", 256),
+                                        ("granite-3-2b", 0)])
+def test_prefill_reports_ssd_chunk(arch, chunk, monkeypatch):
+    """Each `prefill.group` span and `hotpath_stats()["ssd_chunks"]` give
+    the chunk length the flush's Mamba-2 layers ran with: the configured
+    256 for one row of the 1024 bucket, 0 for a model without them."""
+    from repro.serving import engine as engine_mod
+    groups, span = [], engine_mod.span
+
+    def record(name, **args):
+        if name == "prefill.group":
+            groups.append((args["rows"], args["bucket"], args["ssd_chunk"]))
+        return span(name, **args)
+
+    monkeypatch.setattr(engine_mod, "span", record)
+    eng = mk_engine(arch, num_slots=1, max_seq=1024)
+    wl = mk_wl(eng.model.cfg, np.random.default_rng(0), n=1, out_len=1,
+               plo=600, phi=601)
+    eng.run(wl, max_iterations=100)
+    assert groups == [(1, 1024, chunk)]
+    assert eng.hotpath_stats()["ssd_chunks"] == {(1, 1024): chunk}
+
+
 def test_moe_prefill_stays_exact_length():
     """MoE is the one family bucketed prefill CANNOT serve exactly: expert
     capacity is proportional to the forward's total token count (padding

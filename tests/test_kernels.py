@@ -8,10 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs.zamba2_7b import smoke_config
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.selective_scan import selective_scan
+from repro.models import ssm
 
 
 def rand(key, shape, dtype):
@@ -168,10 +170,50 @@ def test_ssd_chunked_matches_sequential():
     B = jax.random.normal(ks[3], (b, s, n))
     C = jax.random.normal(ks[4], (b, s, n))
     D = jnp.ones((nh,)) * 0.3
-    out = ops.ssd(x, dt, A, B, C, D, impl="chunked", chunk=32)
+    out, _ = ssm._ssd_chunked(x, dt, A, B[:, :, None], C[:, :, None], D, 32)
     expect = ref.ssd_ref(x, dt, A, B, C, D)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=2e-4, rtol=2e-3)
+
+
+def _ssd_per_token(x, dt, A, B, C):
+    """The recurrence one token at a time through the decode step."""
+    h0 = jnp.zeros((x.shape[0], x.shape[3], B.shape[-1], x.shape[2]))
+    h, ys = jax.lax.scan(
+        lambda h, t: ssm._ssd_step(h, t[0], t[1], A, t[2], t[3]), h0,
+        tuple(jnp.moveaxis(t, 1, 0) for t in (x, dt, B, C)))
+    return jnp.moveaxis(ys, 0, 1), h
+
+
+@pytest.mark.parametrize("groups,rows,seq,chunk", [
+    (1, 1, 301, 151),       # not a multiple of the 256 cap: two padded
+    (2, 1, 301, 151),
+    (2, 1, 40, 40),         # shorter than the cap: one chunk
+    (2, 2, 512, 256),       # whole chunks of the cap
+    (1, 16, 40, 40),
+    (2, 16, 301, 101),      # 16 rows: the cap halves to 128
+])
+def test_ssd_chunked_matches_per_token(groups, rows, seq, chunk):
+    """The chunked SSD against the per-token recurrence, grouped B/C and
+    right padding masked as the prefill masks it (dt = 0 past each row's
+    length): outputs and final state within f32 reduction order."""
+    cfg = smoke_config()
+    assert ssm.ssd_chunk(cfg, rows, seq) == chunk
+    ks = jax.random.split(jax.random.PRNGKey(rows * seq + groups), 6)
+    nh, hd, n = 4, 8, 8
+    x = jax.random.normal(ks[0], (rows, seq, nh, hd))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (rows, seq, nh)) - 1)
+    A = -jnp.exp(jax.random.normal(ks[2], (nh,)) * 0.5)
+    B = jax.random.normal(ks[3], (rows, seq, groups, n))
+    C = jax.random.normal(ks[4], (rows, seq, groups, n))
+    lengths = jax.random.randint(ks[5], (rows,), 1, seq + 1)
+    dt = jnp.where(jnp.arange(seq)[None, :, None] < lengths[:, None, None],
+                   dt, 0.0)
+    got = ssm._ssd_chunked(x, dt, A, B, C, jnp.zeros((nh,)), chunk)
+    for a, e in zip(got, _ssd_per_token(x, dt, A, B, C)):
+        e = np.asarray(e)
+        np.testing.assert_allclose(np.asarray(a), e, rtol=1e-5,
+                                   atol=1e-5 * np.abs(e).max())
 
 
 def test_scan_step_consistency():
